@@ -19,15 +19,15 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import BACKEND_ROOFLINE, ICI_BW
+from repro.launch.mesh import ICI_BW, TPU_V5E, roofline
 from repro.models.model import INPUT_SHAPES
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
-# Per-backend constants come from the shared table in launch/mesh.py -- the
+# Per-device peaks come from the shared table in launch/mesh.py -- the
 # same numbers the kernel block autotuner keys on, so the bench-reported
 # envelopes and the tuned block shapes can never disagree.  Roofline tables
-# model the TPU target regardless of the host backend running the analysis.
-_TPU = BACKEND_ROOFLINE["tpu"]
+# model the v5e target regardless of the host backend running the analysis.
+_TPU = roofline(TPU_V5E)
 PEAK_FLOPS_BF16 = _TPU["peak_flops"]
 HBM_BW = _TPU["hbm_bw"]
 HBM_PER_CHIP = _TPU["hbm_bytes"]

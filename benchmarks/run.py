@@ -29,6 +29,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = {
     "fig1": "benchmarks.fig1_synthetic",
     "fig2": "benchmarks.fig2_attack",
@@ -148,6 +150,7 @@ def main() -> None:
     ap.add_argument("--compare-tol", type=float, default=0.2,
                     help="relative regression tolerance for --compare")
     args = ap.parse_args()
+    enable_compile_cache()
     quick = not (args.full or os.environ.get("REPRO_BENCH_FULL"))
 
     names = [n.strip() for n in args.only.split(",") if n.strip()] or list(MODULES)

@@ -211,7 +211,9 @@ def _fixture(algo: str, defer_repair: bool):
 
 @lru_cache(maxsize=None)
 def _mesh():
-    return jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((1,), ("data",))
 
 
 def _fault_fixture():
@@ -619,9 +621,9 @@ def _autotune_candidates_contract() -> list[Violation]:
     from repro.kernels import autotune
     from repro.kernels.gp_grad import grad_tiled_spec
     from repro.kernels.gp_score import score_tiled_spec
-    from repro.launch.mesh import BACKEND_ROOFLINE
+    from repro.launch.mesh import TPU_V5E, roofline
 
-    hw = BACKEND_ROOFLINE["tpu"]
+    hw = roofline(TPU_V5E)
     n, cap, d = 256, 1024, 64
     out: list[Violation] = []
     for kind, builder in (("score", score_tiled_spec),
@@ -632,7 +634,7 @@ def _autotune_candidates_contract() -> list[Violation]:
                 if bc > cap:
                     continue  # routes to the resident kernel, not this spec
                 spec = builder(n, cap, d, dt, block_n=bn, block_cap=bc)
-                out += kernel_audit.check_vmem(spec, backend="tpu")
+                out += kernel_audit.check_vmem(spec, device_kind=TPU_V5E)
     return out
 
 

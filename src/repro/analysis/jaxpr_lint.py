@@ -35,12 +35,8 @@ from collections import Counter
 from typing import Iterator, Optional, Sequence
 
 import jax.numpy as jnp
-from jax import core as jcore
-
-try:  # jaxpr source locations (jax internal, but stable across 0.4.x)
-    from jax._src import source_info_util as _src_info
-except Exception:  # pragma: no cover - degrade to location-less reports
-    _src_info = None
+from jax._src import source_info_util  # jaxpr source locations (jax internal)
+from jax.extend import core as jcore
 
 #: Callback primitives: every one of these re-enters Python from inside the
 #: compiled program (and serializes the dispatch pipeline).
@@ -73,13 +69,10 @@ class Violation:
 
 
 def source_of(eqn) -> str:
-    """Best-effort source location of a jaxpr equation."""
-    if _src_info is None or eqn.source_info is None:
+    """Source location of a jaxpr equation (``<unknown>`` when it has none)."""
+    if eqn.source_info is None:
         return "<unknown>"
-    try:
-        return _src_info.summarize(eqn.source_info)
-    except Exception:  # pragma: no cover
-        return "<unknown>"
+    return source_info_util.summarize(eqn.source_info)
 
 
 def _as_jaxpr(obj) -> Optional[jcore.Jaxpr]:

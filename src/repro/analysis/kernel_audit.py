@@ -33,7 +33,7 @@ index maps and proves:
   spec that violates it silently truncates the trailing partial block);
 * **VMEM budget** (``kernel-vmem-budget``): the per-grid-cell footprint
   (double-buffered blocks + scratch, minor axes tile-padded) fits the
-  ``BACKEND_ROOFLINE`` budget -- checked for every block candidate the
+  ``DEVICE_ROOFLINE`` budget -- checked for every block candidate the
   autotuner can emit and for user-pinned ``AlgoConfig`` blocks.
 
 Every violation carries the kernel name and the offending grid cell.
@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from repro.analysis.jaxpr_lint import Violation
 from repro.kernels.spec import KernelSpec
-from repro.launch.mesh import BACKEND_ROOFLINE
+from repro.launch.mesh import TPU_V5E, roofline
 
 
 def _cell(c) -> str:
@@ -223,12 +223,11 @@ def check_geometry(spec: KernelSpec) -> list[Violation]:
     return out
 
 
-def check_vmem(spec: KernelSpec, *, backend: str = "tpu",
+def check_vmem(spec: KernelSpec, *, device_kind: str = TPU_V5E,
                budget: Optional[int] = None) -> list[Violation]:
-    """Per-grid-cell VMEM footprint vs the backend roofline budget."""
+    """Per-grid-cell VMEM footprint vs the device's roofline budget."""
     if budget is None:
-        hw = BACKEND_ROOFLINE.get(backend, BACKEND_ROOFLINE["_default"])
-        budget = hw["vmem_bytes"]
+        budget = roofline(device_kind)["vmem_bytes"]
     need = spec.vmem_cell_bytes()
     if need <= budget:
         return []
@@ -239,15 +238,15 @@ def check_vmem(spec: KernelSpec, *, backend: str = "tpu",
         message=(
             f"{spec.name}: per-grid-cell VMEM footprint {need} B (blocks "
             f"{blocks}, x2 double-buffered, + scratch) exceeds the "
-            f"{backend} budget {budget} B at every grid cell (e.g. "
+            f"{device_kind} budget {budget} B at every grid cell (e.g. "
             f"{_cell(tuple(0 for _ in spec.grid))})"
         ),
         source=spec.name,
     )]
 
 
-def audit_spec(spec: KernelSpec, *, backend: str = "tpu",
+def audit_spec(spec: KernelSpec, *, device_kind: str = TPU_V5E,
                budget: Optional[int] = None) -> list[Violation]:
     """Full static audit: geometry rules + VMEM budget."""
-    return check_geometry(spec) + check_vmem(spec, backend=backend,
+    return check_geometry(spec) + check_vmem(spec, device_kind=device_kind,
                                              budget=budget)

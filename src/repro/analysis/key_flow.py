@@ -27,7 +27,9 @@ identities:
   sub-jaxprs with argument binding, through ``cond`` branches and
   ``while`` bodies, and gives ``scan`` special treatment: a carried key
   that is sampled in the body and returned to the carry UNCHANGED is the
-  ``key-carry-unsplit`` rule (every iteration re-draws the same stream).
+  ``key-carry-unsplit`` rule (every iteration re-draws the same stream);
+  jax forwards such a carry into the scan's consts, so a const key the
+  body samples directly is the same finding.
 
 Findings:
 
@@ -53,7 +55,7 @@ import os
 import re
 from typing import Any, Optional
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.jaxpr_lint import Violation, source_of
 
@@ -277,6 +279,9 @@ class _Analysis:
                 sub_env[bv] = env[av]
             sub_taint[bv] = taint.get(av, False) \
                 if isinstance(av, jcore.Var) else False
+        # uses each const key node had before the body ran (outer keys)
+        uses_before = {bv: len(self.nodes[sub_env[bv]].uses)
+                       for bv in body.invars[:nc] if bv in sub_env}
         # carry and per-iteration xs slots get fresh identities: each
         # iteration sees a different concrete value under one abstract var
         for bv, av in zip(body.invars[nc:], eqn.invars[nc:]):
@@ -287,6 +292,29 @@ class _Analysis:
             self.walk(body, sub_env, sub_taint, path + ("scan",))
         finally:
             self.call_stack.pop()
+        # key-carry-unsplit, loop-invariant form: jax forwards a carry the
+        # body returns unchanged into the scan's CONSTS, so an unsplit
+        # carried key arrives as a const that the body samples directly --
+        # the same draw at every iteration
+        for bv in body.invars[:nc]:
+            nid = sub_env.get(bv)
+            if nid is None:
+                continue
+            fresh = self.nodes[nid].uses[uses_before.get(bv, 0):]
+            samples = [u for u in fresh if u.kind == "sample"]
+            if samples:
+                u = samples[0]
+                self.carry_unsplit.append(Violation(
+                    rule="key-carry-unsplit",
+                    message=(
+                        "PRNG key sampled inside a scan body without a "
+                        f"per-iteration split or fold_in (at {u.source}): "
+                        "the key is loop-invariant, so every iteration "
+                        "re-draws the same stream"
+                    ),
+                    source=u.source,
+                    path=u.path,
+                ))
         # key-carry-unsplit: the body wrapped a carried raw key (binding the
         # carry invar to its node), sampled it, and returned the SAME node
         # as the carry output

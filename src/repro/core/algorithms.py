@@ -23,7 +23,7 @@ under single-process vmap simulation and under shard_map on a device mesh
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -169,7 +169,11 @@ class RoundStats(NamedTuple):
 
 
 def _hyper_of(cfg: AlgoConfig) -> gp.GPHyper:
-    return gp.GPHyper(jnp.asarray(cfg.lengthscale), jnp.asarray(cfg.noise))
+    """The config's GP hyperparameters as static Python floats: inside the
+    jitted round body they stay compile-time constants, so the Pallas kernel
+    wrappers (which bake lengthscale and the derived prior d/l^2 into the
+    program) take the kernel route on the TPU."""
+    return gp.GPHyper(float(cfg.lengthscale), float(cfg.noise))
 
 
 def init_client_state(cfg: AlgoConfig, key: jax.Array, x0: jax.Array,
@@ -465,6 +469,26 @@ def _post_phase_clients(
 # ---------------------------------------------------------------------------
 
 
+def _f32_matmuls(fn):
+    """Trace ``fn`` with float32 matmuls.
+
+    The surrogate's Gram algebra runs at condition numbers ~1e5 (DESIGN.md
+    Sec. 2.4), and the pairwise distances behind it cancel |x|^2 ~ d/4
+    against a cross term, so it needs f32 products.  On a TPU an f32 matmul
+    defaults to one bfloat16 pass; this pins f32 for everything ``fn``
+    traces, Pallas kernels included.  Other backends already compute f32
+    dots in f32, so there it changes nothing.
+    """
+
+    @wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
+@_f32_matmuls
 def run_round(
     cfg: AlgoConfig,
     rff: Optional[rfflib.RFFParams],

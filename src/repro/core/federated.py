@@ -32,7 +32,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import algorithms as alg
@@ -122,12 +121,12 @@ def distributed_round_fn(
 
         in_specs = (cspec, cspec, rspec, rspec)
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         round_body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(cspec, rspec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(shmapped)
 

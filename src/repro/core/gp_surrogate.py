@@ -111,6 +111,14 @@ def sqexp(x1: jax.Array, x2: jax.Array, lengthscale: float) -> jax.Array:
 
 
 def pairwise_sqdist(x1: jax.Array, x2: jax.Array) -> jax.Array:
+    """||x1_i - x2_j||^2 through the norm expansion, in coordinates centred
+    on x1's first row (a live trajectory slot: the ring fills from slot 0).
+    Distances are shift-invariant, but in the original frame the expansion
+    cancels |x|^2 ~ d/4 against the cross term: at d=3072 the f32 rounding
+    (~1e-4) exceeds the 1e-4 jitter floor, the Gram turns numerically
+    indefinite and most factor updates fail."""
+    c = x1[0]
+    x1, x2 = x1 - c, x2 - c
     n1 = jnp.sum(x1 * x1, axis=-1)
     n2 = jnp.sum(x2 * x2, axis=-1)
     cross = x1 @ x2.T
@@ -778,9 +786,12 @@ def grad_mean_cached_clients(
     from repro.kernels import ops  # deferred: keep core importable without kernels
 
     alpha = gp_alpha_cached_clients(trajs, factors)
+    # evaluated in coordinates centred on each query point: the gradient
+    # mean is shift-invariant, and the kernel's distance expansion then
+    # cancels nothing (see pairwise_sqdist)
     out = ops.grad_mean_clients(
-        xs[:, None, :], trajs.xs, alpha, lengthscale=hyper.lengthscale,
-        block_n=block_n, block_cap=block_cap,
+        jnp.zeros_like(xs)[:, None, :], trajs.xs - xs[:, None, :], alpha,
+        lengthscale=hyper.lengthscale, block_n=block_n, block_cap=block_cap,
     )
     return out[:, 0, :]
 

@@ -45,7 +45,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.extend import core as jex_core
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.checkpoint import io as ckpt_io
@@ -208,12 +208,12 @@ def dist_chunk_fn(
         )
         return states, sx, ys
 
-    return shard_map(
+    return jax.shard_map(
         local_chunk,
         mesh=mesh,
         in_specs=(cspec, cspec, rspec, rspec),
         out_specs=(cspec, rspec, rspec),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -234,16 +234,51 @@ def _hist_write(hist: alg.SimResult, ys, offset: jax.Array) -> alg.SimResult:
     )
 
 
-def make_chunk_step(chunk_fn):
-    """Jit one chunk step.  The client states and the history buffers are
-    donated: the engine mutates them in place across the whole run."""
+class ChunkStep:
+    """One jitted chunk step: ``step(states, hist, cobjs, sx, offset)``.
 
-    @partial(jax.jit, donate_argnums=(0, 1))
-    def step(states, hist, cobjs, sx, offset):
-        states, sx, ys = chunk_fn(states, cobjs, sx, offset)
-        return states, _hist_write(hist, ys, offset), sx
+    The client states and the history buffers are donated: the engine
+    mutates them in place across the whole run.  Arrays that ``chunk_fn``
+    closes over -- the RFF map, the weights of a model-backed objective --
+    reach the executable as ARGUMENTS: left in the closure, jit would embed
+    them in the program as literals, putting a model's weights into every
+    compiled chunk step and compile-cache entry.
+    """
 
-    return step
+    def __init__(self, chunk_fn):
+        self._chunk_fn = chunk_fn
+        self._traced: dict = {}  # argument structure/shapes -> (jitted, arrays)
+
+    def _get(self, states, cobjs, sx, offset):
+        args = (states, cobjs, sx, offset)
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        key = (tree, tuple((np.shape(a), str(jnp.result_type(a))) for a in leaves))
+        if key not in self._traced:
+            closed, out_shape = jax.make_jaxpr(self._chunk_fn, return_shape=True)(*args)
+            body, out_tree = closed.jaxpr, jax.tree_util.tree_structure(out_shape)
+
+            @partial(jax.jit, donate_argnums=(1, 2))
+            def run(consts, states, hist, cobjs, sx, offset):
+                flat = jax.tree_util.tree_leaves((states, cobjs, sx, offset))
+                outs = jex_core.jaxpr_as_fun(jex_core.ClosedJaxpr(body, consts))(*flat)
+                states, sx, ys = jax.tree_util.tree_unflatten(out_tree, outs)
+                return states, _hist_write(hist, ys, offset), sx
+
+            self._traced[key] = (run, list(closed.consts))
+        return self._traced[key]
+
+    def __call__(self, states, hist, cobjs, sx, offset):
+        run, consts = self._get(states, cobjs, sx, offset)
+        return run(consts, states, hist, cobjs, sx, offset)
+
+    def lower(self, states, hist, cobjs, sx, offset):
+        run, consts = self._get(states, cobjs, sx, offset)
+        return run.lower(consts, states, hist, cobjs, sx, offset)
+
+
+def make_chunk_step(chunk_fn) -> ChunkStep:
+    """Jit one chunk step (see ``ChunkStep``)."""
+    return ChunkStep(chunk_fn)
 
 
 # ---------------------------------------------------------------------------
@@ -289,12 +324,12 @@ def boundary_repair_on_device(
             axes = fed.client_axes(mesh)
             cspec = P(axes)
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     gp.factor_repair_gated,
                     mesh=mesh,
                     in_specs=(cspec, P()),
                     out_specs=cspec,
-                    check_rep=False,
+                    check_vma=False,
                 ),
                 donate_argnums=0,
             )
@@ -354,12 +389,12 @@ def repair_flagged_clients(
         axes = fed.client_axes(mesh)
         cspec = P(axes)
         _DIST_REPAIR_CACHE[key] = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda fac, jit_: gp.factor_repair_masked(fac, jit_),
                 mesh=mesh,
                 in_specs=(cspec, P()),
                 out_specs=cspec,
-                check_rep=False,
+                check_vma=False,
             )
         )
     factor = _DIST_REPAIR_CACHE[key](states.factor, jitter)
@@ -390,12 +425,12 @@ def _quarantine_reset_exec(cfg: alg.AlgoConfig, mesh: Optional[Mesh], shape):
             axes = fed.client_axes(mesh)
             cspec = P(axes)
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     gated,
                     mesh=mesh,
                     in_specs=(cspec, P()),
                     out_specs=cspec,
-                    check_rep=False,
+                    check_vma=False,
                 ),
                 donate_argnums=0,
             )

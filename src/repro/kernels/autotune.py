@@ -2,10 +2,10 @@
 
 ``select_blocks(kind, ...)`` picks ``(block_n, block_cap)`` for the
 cap-tiled scoring / grad-mean kernels from a VMEM-footprint +
-arithmetic-intensity model keyed on the per-backend roofline constants in
-``repro.launch.mesh.BACKEND_ROOFLINE`` (the same table
+arithmetic-intensity model keyed on the per-device roofline peaks in
+``repro.launch.mesh.DEVICE_ROOFLINE`` (the same table
 ``benchmarks/roofline.py`` reports against).  The choice is a pure function
-of ``(backend, kind, n_clients, n, cap, d)`` -- deterministic and therefore
+of ``(device_kind, kind, n_clients, n, cap, d)`` -- deterministic and therefore
 reproducible -- and is memoized in a process-level cache under exactly that
 key.  Callers that need a specific tiling (tests, `AlgoConfig` overrides)
 bypass the tuner by passing explicit block sizes to the ops wrappers.
@@ -14,13 +14,13 @@ The model is intentionally small:
 
 * **feasibility** -- the per-grid-cell VMEM working set (input tiles,
   intermediate (bn, bc) tiles, accumulators, x2 for double buffering) must
-  fit the backend's ``vmem_bytes`` budget;
+  fit the device's ``vmem_bytes`` budget;
 * **cost** -- per-cell ``max(flops/peak, hbm_bytes/bw)`` summed over the
   padded grid, so oversized blocks pay their padding waste and undersized
   ones pay the re-streamed (bc, bc) Gram tiles and recomputed h tiles.
 
-For backends missing from the table the ``_default`` entry keeps the choice
-deterministic; ``measure_blocks`` is the measured-sweep fallback that times
+A device missing from the table raises ``UnknownDeviceError``: a VMEM budget
+is never guessed.  ``measure_blocks`` is the measured sweep that times
 real kernel calls over the feasible candidate grid and caches the argmin
 under the same key (an explicit API: it blocks on device results, so it
 cannot run under a jit trace the way ``select_blocks`` can).
@@ -34,7 +34,7 @@ from typing import Any, Callable, Iterable, Optional
 import jax
 import numpy as np
 
-from repro.launch.mesh import BACKEND_ROOFLINE
+from repro.launch.mesh import local_device_kind, roofline
 
 #: f32 tile alignment of the TPU vector unit: (sublane, lane).
 _SUBLANE = 8
@@ -64,9 +64,9 @@ def _dtype_name(dtype: Any) -> str:
     return np.dtype(jax.dtypes.canonicalize_dtype(dtype or np.float32)).name
 
 
-def cache_key(kind: str, backend: str, n_clients: int, n: int, cap: int,
+def cache_key(kind: str, device_kind: str, n_clients: int, n: int, cap: int,
               d: int, dtype: Any = None):
-    return (backend, kind, n_clients, n, cap, d, _dtype_name(dtype))
+    return (device_kind, kind, n_clients, n, cap, d, _dtype_name(dtype))
 
 
 def clear_cache() -> None:
@@ -135,19 +135,19 @@ def validate_blocks(
     block_cap: int,
     cap: int,
     d: int,
-    backend: Optional[str] = None,
+    device_kind: Optional[str] = None,
     dtype: Any = None,
 ) -> tuple[int, int]:
     """Validate a USER-PINNED ``(block_n, block_cap)`` pair against the
-    backend VMEM budget -- the same footprint model and 0.75 budget the
+    device's VMEM budget -- the same footprint model and 0.75 budget the
     tuner's feasibility filter uses -- and raise a loud ``ValueError``
     naming the block and the budget when it cannot fit.  Tuner-chosen
     blocks are feasible by construction; explicit ``AlgoConfig`` pins are
     not, and an infeasible pin would otherwise surface as an opaque
     Mosaic/XLA allocation failure deep inside the round body.
     """
-    backend = backend or jax.default_backend()
-    hw = BACKEND_ROOFLINE.get(backend, BACKEND_ROOFLINE["_default"])
+    device_kind = device_kind or local_device_kind()
+    hw = roofline(device_kind)
     budget = int(0.75 * hw["vmem_bytes"])
     itemsize = np.dtype(_dtype_name(dtype)).itemsize
     # block_cap >= cap routes to the VMEM-resident kernel: the working set
@@ -158,7 +158,7 @@ def validate_blocks(
         raise ValueError(
             f"pinned {kind} blocks (block_n={block_n}, block_cap={block_cap})"
             f" need {need} bytes of VMEM per grid cell at d={d} "
-            f"({_dtype_name(dtype)}), exceeding the {backend!r} budget of "
+            f"({_dtype_name(dtype)}), exceeding the {device_kind!r} budget of "
             f"{budget} bytes (0.75 x vmem_bytes={hw['vmem_bytes']}); pick "
             "smaller AlgoConfig block pins or leave them unset for the "
             "autotuner"
@@ -173,7 +173,7 @@ def select_blocks(
     cap: int,
     d: int,
     n_clients: int = 1,
-    backend: Optional[str] = None,
+    device_kind: Optional[str] = None,
     dtype: Any = None,
 ) -> tuple[int, int]:
     """Deterministic ``(block_n, block_cap)`` for a kernel ``kind``/shape.
@@ -186,13 +186,13 @@ def select_blocks(
     narrower dtypes widen the feasible set and shift the roofline balance,
     and are cached under their own key.
     """
-    backend = backend or jax.default_backend()
-    key = cache_key(kind, backend, n_clients, n, cap, d, dtype)
+    device_kind = device_kind or local_device_kind()
+    key = cache_key(kind, device_kind, n_clients, n, cap, d, dtype)
     hit = _CACHE.get(key)
     if hit is not None:
         return hit
     itemsize = np.dtype(_dtype_name(dtype)).itemsize
-    hw = BACKEND_ROOFLINE.get(backend, BACKEND_ROOFLINE["_default"])
+    hw = roofline(device_kind)
     best: Optional[tuple[float, tuple[int, int]]] = None
     for bn, bc in _feasible(kind, n, cap, d, hw, itemsize):
         cost = _cell_cost(kind, bn, bc, d, hw, itemsize) * _grid_cells(kind, bn, bc, n, cap, n_clients)
@@ -215,7 +215,7 @@ def measure_blocks(
     cap: int,
     d: int,
     n_clients: int = 1,
-    backend: Optional[str] = None,
+    device_kind: Optional[str] = None,
     dtype: Any = None,
     candidates: Optional[Iterable[tuple[int, int]]] = None,
     reps: int = 3,
@@ -226,8 +226,8 @@ def measure_blocks(
     the measured choice.  Explicit API only -- it calls
     ``block_until_ready`` and so cannot run under a jit trace.
     """
-    backend = backend or jax.default_backend()
-    hw = BACKEND_ROOFLINE.get(backend, BACKEND_ROOFLINE["_default"])
+    device_kind = device_kind or local_device_kind()
+    hw = roofline(device_kind)
     itemsize = np.dtype(_dtype_name(dtype)).itemsize
     cands = list(candidates) if candidates is not None else list(
         _feasible(kind, n, cap, d, hw, itemsize)
@@ -244,5 +244,5 @@ def measure_blocks(
             dt = min(dt, time.perf_counter() - t0)
         if best is None or dt < best[0]:
             best = (dt, (bn, bc))
-    _CACHE[cache_key(kind, backend, n_clients, n, cap, d, dtype)] = best[1]
+    _CACHE[cache_key(kind, device_kind, n_clients, n, cap, d, dtype)] = best[1]
     return best[1]

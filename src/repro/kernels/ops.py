@@ -1,9 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles block-size padding (zero-pad, slice back), block-size selection and
-backend selection: on TPU the Pallas kernels run compiled; elsewhere they
-run in interpret mode when ``force_pallas`` (used by tests) or fall back to
-the jnp oracles in ref.py, which are numerically identical.
+backend selection: on TPU the Pallas kernels run compiled; elsewhere the
+jnp oracles in ref.py run, which are numerically identical, and the kernels
+run in interpret mode only when ``force_pallas`` (used by tests).  The route
+is decided by the backend alone: the kernels bake their scalars
+(lengthscale, prior) into the program, so on the kernel route those must be
+static Python floats, and a traced one is an error (``_static_float``).
 
 The GP kernels (scoring / grad mean) take ``block_n`` / ``block_cap``; when
 left ``None`` the tuner in ``kernels/autotune.py`` picks them
@@ -41,18 +44,20 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _static_float(x) -> float | None:
-    """Concrete python float, or None for a traced value.
+def _static_float(x) -> float:
+    """A kernel scalar as a Python float.
 
     The Pallas kernels bake scalars (lengthscale, prior) into the program as
-    compile-time constants; when a caller threads TRACED hyperparameters
-    (e.g. the federated round loop jits over GPHyper arrays) the wrappers
-    fall back to the jnp oracle, which XLA fuses well on every backend.
+    compile-time constants.  The engine passes its static ``AlgoConfig``
+    floats (``algorithms._hyper_of``); a traced scalar cannot be baked in,
+    and taking the oracle instead would hide that the kernel never ran.
     """
-    try:
-        return float(x)
-    except (TypeError, jax.errors.TracerArrayConversionError, jax.errors.ConcretizationTypeError):
-        return None
+    if isinstance(x, jax.core.Tracer):
+        raise TypeError(
+            "a traced kernel scalar (lengthscale/prior) reached a Pallas "
+            "kernel wrapper; pass static Python floats on the kernel route"
+        )
+    return float(x)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -172,7 +177,7 @@ def sqexp(
     npad, mpad = _round_up(n, block_n), _round_up(m, block_m)
     out = sqexp_kernel(
         _pad_rows(x1, npad), _pad_rows(x2, mpad),
-        lengthscale=lengthscale, block_n=block_n, block_m=block_m,
+        lengthscale=_static_float(lengthscale), block_n=block_n, block_m=block_m,
         interpret=not _on_tpu(),
     )
     return out[:n, :m]
@@ -199,12 +204,12 @@ def uncertainty_scores(
     zero-padded to a tile multiple (padded slots contribute exactly zero:
     the B/P padding rows+columns are zero); otherwise the whole (cap, cap)
     factor pair stays VMEM-resident.  Unset block sizes come from the
-    deterministic autotuner.  Traced lengthscale/prior fall back to the jnp
-    oracle.
+    deterministic autotuner.  On the kernel route ``lengthscale`` and
+    ``prior`` must be static (``_static_float``).
     """
-    ls, pr = _static_float(lengthscale), _static_float(prior)
-    if not (_on_tpu() or force_pallas) or ls is None or pr is None:
+    if not (_on_tpu() or force_pallas):
         return ref.uncertainty_scores(cands, xs, binv, pmat, lengthscale, prior)
+    ls, pr = _static_float(lengthscale), _static_float(prior)
     n, d = cands.shape
     cap = xs.shape[0]
     block_n, block_cap = _resolve_blocks(
@@ -243,17 +248,17 @@ def uncertainty_scores_clients(
     """Client-batched fused uncertainty scores: (N, n, d) -> (N, n).
 
     One kernel launch with a client grid dimension for the whole batch;
-    same padding/backend/traced-scalar/tiling contract as
+    same padding/backend/static-scalar/tiling contract as
     ``uncertainty_scores`` (the candidate and trajectory axes are padded per
     client, the client axis never is).  The CPU execution path is the
     fused-epilogue contraction (``ref.uncertainty_scores_clients_fused``);
     the textbook oracle stays in ``ref.uncertainty_scores_clients``.
     """
-    ls, pr = _static_float(lengthscale), _static_float(prior)
-    if not (_on_tpu() or force_pallas) or ls is None or pr is None:
+    if not (_on_tpu() or force_pallas):
         return ref.uncertainty_scores_clients_fused(
             cands, xs, binv, pmat, lengthscale, prior
         )
+    ls, pr = _static_float(lengthscale), _static_float(prior)
     nb, n, d = cands.shape
     cap = xs.shape[1]
     block_n, block_cap = _resolve_blocks(
@@ -293,9 +298,9 @@ def grad_mean_clients(
     With ``block_cap < cap`` the cap-tiled accumulator kernel runs; padded
     trajectory slots carry alpha == 0 and contribute exactly zero.
     """
-    ls = _static_float(lengthscale)
-    if not (_on_tpu() or force_pallas) or ls is None:
+    if not (_on_tpu() or force_pallas):
         return ref.grad_mean_clients(cands, xs, alpha, lengthscale)
+    ls = _static_float(lengthscale)
     nb, n, d = cands.shape
     cap = xs.shape[1]
     block_n, block_cap = _resolve_blocks(
@@ -335,11 +340,11 @@ def grad_mean_batch(
     leave invalid slots exactly zero, so padded trajectory slots contribute
     nothing -- the same invariant makes cap-axis zero-padding exact on the
     tiled path).  Padded candidate rows are sliced away before returning.
-    Traced lengthscale falls back to the jnp oracle.
+    On the kernel route ``lengthscale`` must be static.
     """
-    ls = _static_float(lengthscale)
-    if not (_on_tpu() or force_pallas) or ls is None:
+    if not (_on_tpu() or force_pallas):
         return ref.grad_mean_batch(cands, xs, alpha, lengthscale)
+    ls = _static_float(lengthscale)
     n, d = cands.shape
     cap = xs.shape[0]
     block_n, block_cap = _resolve_blocks(

@@ -32,6 +32,7 @@ from repro.core import model_objectives as mobj
 from repro.core import objectives as obj
 from repro.core.federated import run_distributed
 from repro.launch import common
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -77,6 +78,7 @@ def main() -> None:
     common.add_algo_flags(ap)  # the shared AlgoConfig flag surface
     common.add_engine_flags(ap)  # --chunk / --ckpt-dir / --eval-every / pool
     args = ap.parse_args()
+    enable_compile_cache()
 
     pool_size, cohort = common.pool_from_args(args)
     if pool_size is not None:

@@ -21,7 +21,7 @@ import threading
 from typing import Any, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
@@ -114,9 +114,14 @@ def constrain(x: jax.Array, *axes) -> jax.Array:
     replicated on the batch dim was measured to cost 80 GB/device of
     all-gathered attention temporaries on qwen1.5 train_4k (EXPERIMENTS.md
     §Perf).  Input/param shardings (spec_with_fallback) keep None=replicated.
+
+    The hint steers GSPMD propagation, which only Auto axes take part in
+    (``launch.mesh.make_mesh`` builds every mesh of the repo so).  On a mesh
+    with Explicit or Manual axes the sharding is part of the array's type
+    and UNCONSTRAINED dims are refused, so the hint is skipped there.
     """
     mesh = get_mesh()
-    if mesh is None:
+    if mesh is None or any(t != AxisType.Auto for t in mesh.axis_types):
         return x
     unc = P.UNCONSTRAINED
     full = tuple(axes) + (None,) * (x.ndim - len(axes))

@@ -5,96 +5,11 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
+from hypothesis import settings
 
 jax.config.update("jax_enable_x64", False)
 
-
-# ---------------------------------------------------------------------------
-# hypothesis shim
-#
-# The container image has no `hypothesis` wheel and installs are not allowed,
-# which made every property-test module fail at COLLECTION.  The tests only
-# use a tiny slice of the API (given / settings / st.integers / st.floats),
-# so when the real package is absent we install a deterministic stand-in that
-# runs each property test over `max_examples` seeded pseudo-random samples.
-# With the real package installed this block is inert.
-# ---------------------------------------------------------------------------
-try:  # pragma: no cover - exercised only when hypothesis exists
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - the container path
-
-    import random
-    import sys
-    import types
-    import zlib
-
-    class _Strategy:
-        def __init__(self, sample):
-            self.sample = sample
-
-    def _integers(min_value, max_value):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _floats(min_value, max_value, **_kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def _booleans():
-        return _Strategy(lambda rng: bool(rng.getrandbits(1)))
-
-    def _sampled_from(options):
-        opts = list(options)
-        return _Strategy(lambda rng: opts[rng.randrange(len(opts))])
-
-    def _given(**strategies):
-        def deco(fn):
-            def wrapper():
-                n = getattr(wrapper, "_stub_max_examples", 10)
-                rng = random.Random(zlib.crc32(fn.__qualname__.encode()))
-                for _ in range(n):
-                    drawn = {k: s.sample(rng) for k, s in strategies.items()}
-                    try:
-                        fn(**drawn)
-                    except _hyp.UnsatisfiedAssumption:
-                        continue
-
-            # Do NOT functools.wraps: pytest would follow __wrapped__ and
-            # treat the property arguments as missing fixtures.
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper.__qualname__ = fn.__qualname__
-            wrapper._stub_max_examples = getattr(fn, "_stub_max_examples", 10)
-            if hasattr(fn, "pytestmark"):
-                wrapper.pytestmark = fn.pytestmark
-            return wrapper
-
-        return deco
-
-    def _settings(max_examples=10, **_kw):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-
-        return deco
-
-    def _assume(condition):
-        if not condition:
-            raise _hyp.UnsatisfiedAssumption()
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.booleans = _booleans
-    _st.sampled_from = _sampled_from
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = _assume
-    _hyp.strategies = _st
-    _hyp.UnsatisfiedAssumption = type("UnsatisfiedAssumption", (Exception,), {})
-    _hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    _hyp.__is_repro_stub__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
+# No on-disk example database: a run's examples depend only on committed
+# files, never on failures replayed from an earlier run's .hypothesis/.
+settings.register_profile("repro", database=None)
+settings.load_profile("repro")

@@ -32,6 +32,7 @@ from repro.analysis import (
 )
 from repro.analysis import hlo_audit, jaxpr_lint, kernel_audit, key_flow
 from repro.kernels.spec import ArraySpec, BlockDecl, KernelSpec, ScratchDecl
+from repro.launch.mesh import TPU_V5E
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +278,7 @@ def test_over_budget_block_pick_caught():
 
     spec = score_tiled_spec(256, 2048, 256, jnp.float32,
                             block_n=256, block_cap=1024)
-    vs = kernel_audit.check_vmem(spec, backend="tpu")
+    vs = kernel_audit.check_vmem(spec, device_kind=TPU_V5E)
     assert [v.rule for v in vs] == ["kernel-vmem-budget"]
     assert "gp_score.tiled" in vs[0].message
     assert "budget" in vs[0].message and "(0, 0, 0)" in vs[0].message
@@ -285,10 +286,10 @@ def test_over_budget_block_pick_caught():
     from repro.kernels import autotune
 
     bn, bc = autotune.select_blocks("score", n=256, cap=2048, d=256,
-                                    backend="tpu")
+                                    device_kind=TPU_V5E)
     ok = score_tiled_spec(256, 2048, 256, jnp.float32, block_n=bn,
                           block_cap=min(bc, 2048))
-    assert kernel_audit.check_vmem(ok, backend="tpu") == []
+    assert kernel_audit.check_vmem(ok, device_kind=TPU_V5E) == []
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ from repro.checkpoint import io as ckpt_io
 from repro.core import algorithms as alg
 from repro.core import rounds as rounds_mod
 from repro.core.federated import shard_clients
+from repro.launch.mesh import make_mesh
 
 REPO_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
 
@@ -94,7 +95,7 @@ def test_sharded_roundtrip_bitwise_vs_gathered(tmp_path):
     """mesh save/restore == the original state == the legacy gathered
     layout, leaf for leaf, bit for bit (incl. the bool repair flags and
     int32 counters)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     states, hist = _state_and_hist(mesh)
 
     shard_root = str(tmp_path / "sharded")
@@ -131,7 +132,7 @@ def test_sharded_roundtrip_bitwise_vs_gathered(tmp_path):
 def test_sharded_manifest_rejects_mismatched_topology(tmp_path):
     """{n_shards, mesh} in the manifest are validated loudly; a sharded
     checkpoint also refuses to restore without a mesh at all."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     states, hist = _state_and_hist(mesh)
     root = str(tmp_path / "m")
     ckpt_io.save_round_state(root, 2, states, hist, mesh=mesh)
@@ -158,7 +159,7 @@ def test_sharded_manifest_rejects_mismatched_topology(tmp_path):
 
 def test_sharded_dtype_and_shape_validated(tmp_path):
     """The per-leaf shape/dtype contract holds on the sharded path too."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     states, hist = _state_and_hist(mesh)
     root = str(tmp_path / "v")
     ckpt_io.save_round_state(root, 2, states, hist, mesh=mesh)
@@ -173,7 +174,7 @@ def test_sharded_dtype_and_shape_validated(tmp_path):
 def test_sharded_tmp_recovery(tmp_path):
     """A preemption mid-sharded-write leaves only ``step_*.tmp``; resume
     must fall back to the last COMPLETE checkpoint."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     states, hist = _state_and_hist(mesh)
     root = str(tmp_path / "t")
     ckpt_io.save_round_state(root, 4, states, hist, mesh=mesh)
@@ -270,7 +271,7 @@ def test_run_rounds_sharded_resume_bitwise(tmp_path):
     from repro.core import objectives as obj
     from repro.core.federated import run_distributed
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     quad = obj.make_quadratic(jax.random.PRNGKey(0), 4, 8, 2.0, 0.001)
     cfg = _fzoos_cfg()
     k = jax.random.PRNGKey(5)
@@ -298,13 +299,14 @@ MULTIDEV_SHARD_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     os.environ["JAX_PLATFORMS"] = "cpu"  # skip the (slow) accelerator probe
     import jax, jax.numpy as jnp, numpy as np, tempfile
+    from repro.launch.mesh import make_mesh
     from repro.checkpoint import io as ckpt_io
     from repro.core.federated import shard_clients
 
     # A synthetic client-stacked pytree: the io layer only sees leaves with a
     # leading client axis, so a real ClientState (whose init compiles for
     # minutes on 4 host devices) adds nothing here.
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     states = shard_clients(mesh, {
         "x": jnp.arange(8 * 6, dtype=jnp.float32).reshape(8, 6),
         "flags": jnp.asarray([0, 1, 0, 0, 1, 0, 1, 0], bool),

@@ -22,6 +22,7 @@ from repro.core import algorithms as alg
 from repro.core import gp_surrogate as gp
 from repro.core import objectives as obj
 from repro.core import rounds as rounds_mod
+from repro.launch.mesh import make_mesh
 
 
 def _fzoos_cfg(**kw):
@@ -194,7 +195,7 @@ def test_deferred_engine_matches_inline_oracle_distributed(quad):
     """Same oracle contract through shard_map (per-shard repair path)."""
     from repro.core.federated import run_distributed
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     k = jax.random.PRNGKey(5)
     args = (k, quad, obj.quadratic_query, obj.quadratic_global_value, 6)
     r_inline = run_distributed(_fzoos_cfg(defer_repair=False), mesh, *args, chunk=3)
@@ -302,7 +303,7 @@ def test_device_repair_matches_host_oracle(quad, use_mesh):
     """The device-decided boundary (`boundary_repair_on_device`) == the
     host-read oracle (`repair_flagged_clients`), leaf for leaf, on both
     engines -- including n_refactors accounting and flag clearing."""
-    mesh = jax.make_mesh((1,), ("data",)) if use_mesh else None
+    mesh = make_mesh((1,), ("data",)) if use_mesh else None
     cfg = _fzoos_cfg()
     states = alg.init_states(cfg, jax.random.PRNGKey(1), jnp.full((8,), 0.5))
     flags = jnp.asarray([True, False, False, True])
@@ -363,7 +364,7 @@ def test_steady_state_boundary_issues_no_device_get(quad):
     history return."""
     from repro.core import rff as rfflib
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = _fzoos_cfg()
     x0 = jnp.full((8,), 0.5, jnp.float32)
     rff = rfflib.make_rff(jax.random.PRNGKey(1), cfg.n_features, cfg.dim,

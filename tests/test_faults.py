@@ -28,6 +28,7 @@ from repro.core import objectives as obj
 from repro.core import rounds as rounds_mod
 from repro.core.federated import run_distributed
 from repro.faults import FaultConfig, corrupt, draw_faults, schedule_table
+from repro.launch.mesh import make_mesh
 
 ROUNDS = 8
 
@@ -51,7 +52,7 @@ def _sim(cfg, quad, rounds=ROUNDS, **kw):
 
 
 def _dist(cfg, quad, rounds=ROUNDS, **kw):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     return run_distributed(cfg, mesh, jax.random.PRNGKey(5), quad,
                            obj.quadratic_query, obj.quadratic_global_value,
                            rounds, **kw)

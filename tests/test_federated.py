@@ -19,6 +19,7 @@ import pytest
 from repro.core import algorithms as alg
 from repro.core import objectives as obj
 from repro.core.federated import client_axes, distributed_round_fn, run_distributed
+from repro.launch.mesh import make_mesh
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -34,7 +35,7 @@ def test_distributed_single_device_matches_vmap_sim():
     the seed's 1e-4 round-1 assertion was failing for exactly this reason.
     What is guaranteed: bounded divergence of iterates and objective curves.
     """
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     key = jax.random.PRNGKey(0)
     cobjs = obj.make_quadratic(key, 4, 8, 2.0, 0.001)
     cfg = alg.AlgoConfig(name="fzoos", dim=8, n_clients=4, local_steps=3,
@@ -51,12 +52,12 @@ def test_distributed_single_device_matches_vmap_sim():
 
 
 def test_client_axes_excludes_model():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert client_axes(mesh) == ("data",)
 
 
 def test_distributed_round_rejects_indivisible_clients():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = alg.AlgoConfig(name="fedzo", dim=4, n_clients=3, local_steps=2)
     # 3 clients on 1 shard is fine; the error path needs shards > clients,
     # which needs >1 device -- covered in the subprocess test below.
@@ -69,11 +70,12 @@ MULTIDEV_SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.core import algorithms as alg
     from repro.core import objectives as obj
     from repro.core.federated import run_distributed
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     key = jax.random.PRNGKey(0)
     cobjs = obj.make_quadratic(key, 8, 10, 5.0, 0.001)
     cfg = alg.AlgoConfig(name="fzoos", dim=10, n_clients=8, local_steps=3,
@@ -104,7 +106,7 @@ def test_distributed_four_devices_matches_sim_subprocess():
     """8 clients sharded over a 4-device mesh == vmap simulation."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # skip the (slow) accelerator probe
     out = subprocess.run(
         [sys.executable, "-c", MULTIDEV_SCRIPT], env=env,
         capture_output=True, text=True, timeout=900,

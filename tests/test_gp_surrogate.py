@@ -3,7 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import gp_surrogate as gp
 
@@ -42,6 +42,7 @@ def test_grad_mean_approximates_true_gradient_with_dense_data():
     extra_cap=st.integers(1, 40),
     seed=st.integers(0, 2**31 - 1),
 )
+@example(n=17, extra_cap=21, seed=17519)
 def test_padding_invariance(n, extra_cap, seed):
     """The masked padded Gram solve must equal the exact-capacity solve."""
     d = 3

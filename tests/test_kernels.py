@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.launch.mesh import TPU_V5E
 
 SHAPES = [
     (8, 16, 64),      # tiny, all-padding path
@@ -233,35 +234,45 @@ def test_clients_kernels_candidate_padding_invariance():
 
 
 def test_clients_kernels_traced_hyper_fall_back_to_oracle():
-    cands, xs, binv, pmat, _ = _gp_data_clients(2, 16, 4, 32)
+    """Only the oracle route takes a traced lengthscale.  On the kernel
+    route a traced scalar is an error: it is never silently swapped for the
+    oracle (which would make a kernel run look like one that never was)."""
+    cands, xs, binv, pmat, alpha = _gp_data_clients(2, 16, 4, 32)
 
-    @jax.jit
-    def scores(ls):
+    def scores(ls, force):
         return ops.uncertainty_scores_clients(
             cands, xs, binv, pmat, lengthscale=ls, prior=4.0 / ls**2,
-            force_pallas=True,
+            force_pallas=force,
         )
 
-    got = scores(jnp.asarray(0.8))
+    got = jax.jit(lambda ls: scores(ls, False))(jnp.asarray(0.8))
     want = ref.uncertainty_scores_clients(cands, xs, binv, pmat, 0.8, 4.0 / 0.64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    with pytest.raises(TypeError, match="traced kernel scalar"):
+        jax.jit(lambda ls: scores(ls, True))(jnp.asarray(0.8))
+    with pytest.raises(TypeError, match="traced kernel scalar"):
+        jax.jit(lambda ls: ops.grad_mean_clients(
+            cands, xs, alpha, lengthscale=ls, force_pallas=True))(jnp.asarray(0.8))
 
 
 def test_gp_kernels_traced_hyper_fall_back_to_oracle():
-    """Traced lengthscale (e.g. inside the jitted round loop) must not
-    attempt to bake a tracer into the Pallas program."""
+    """Unbatched twins of the test above."""
     cands, xs, binv, pmat, alpha = _gp_data(16, 4, 32)
 
-    @jax.jit
-    def scores(ls):
+    def scores(ls, force):
         return ops.uncertainty_scores(
             cands, xs, binv, pmat, lengthscale=ls, prior=4.0 / ls**2,
-            force_pallas=True,
+            force_pallas=force,
         )
 
-    got = scores(jnp.asarray(0.8))
+    got = jax.jit(lambda ls: scores(ls, False))(jnp.asarray(0.8))
     want = ref.uncertainty_scores(cands, xs, binv, pmat, 0.8, 4.0 / 0.64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    with pytest.raises(TypeError, match="traced kernel scalar"):
+        jax.jit(lambda ls: scores(ls, True))(jnp.asarray(0.8))
+    with pytest.raises(TypeError, match="traced kernel scalar"):
+        jax.jit(lambda ls: ops.grad_mean_batch(
+            cands, xs, alpha, lengthscale=ls, force_pallas=True))(jnp.asarray(0.8))
 
 
 # ---------------------------------------------------------------------------
@@ -444,8 +455,8 @@ def test_autotune_deterministic_and_feasible():
 
     autotune.clear_cache()
     picks = [
-        autotune.select_blocks("score", n=100, cap=1024, d=20, n_clients=64, backend=b)
-        for b in ("tpu", "cpu", "tpu")
+        autotune.select_blocks("score", n=100, cap=1024, d=20, n_clients=64, device_kind=k)
+        for k in (TPU_V5E, "cpu", TPU_V5E)
     ]
     assert picks[0] == picks[2]  # deterministic (and cached)
     for bn, bc in picks:
@@ -454,8 +465,26 @@ def test_autotune_deterministic_and_feasible():
     # The scale-out premise: cap=1024 does NOT fit resident on tpu VMEM.
     assert picks[0][1] < 1024
     # Small shapes stay resident (no tiling overhead when everything fits).
-    bn, bc = autotune.select_blocks("score", n=100, cap=128, d=20, n_clients=8, backend="tpu")
+    bn, bc = autotune.select_blocks("score", n=100, cap=128, d=20, n_clients=8, device_kind=TPU_V5E)
     assert bc >= 128
+
+
+def test_autotune_unknown_device_kind_raises():
+    """A device missing from the roofline table is an error, never a default
+    VMEM budget."""
+    from repro.kernels import autotune
+    from repro.launch.mesh import UnknownDeviceError
+
+    autotune.clear_cache()
+    for call in (
+        lambda: autotune.select_blocks("score", n=8, cap=128, d=16, device_kind="TPU v9"),
+        lambda: autotune.validate_blocks("grad", block_n=8, block_cap=128, cap=128,
+                                         d=16, device_kind="TPU v9"),
+        lambda: autotune.measure_blocks("grad", lambda bn, bc: None, n=8, cap=128,
+                                        d=16, device_kind="TPU v9"),
+    ):
+        with pytest.raises(UnknownDeviceError, match="TPU v9"):
+            call()
 
 
 def test_autotune_dtype_keys_cache_and_default_is_f32():
@@ -464,14 +493,14 @@ def test_autotune_dtype_keys_cache_and_default_is_f32():
     from repro.kernels import autotune
 
     autotune.clear_cache()
-    shape = dict(n=64, cap=2048, d=16, n_clients=4, backend="tpu")
+    shape = dict(n=64, cap=2048, d=16, n_clients=4, device_kind=TPU_V5E)
     default = autotune.select_blocks("score", **shape)
     explicit = autotune.select_blocks("score", **shape, dtype=jnp.float32)
     assert default == explicit
     # Distinct key components per dtype, f32 key == no-dtype key.
-    kf = autotune.cache_key("score", "tpu", 4, 64, 2048, 16)
-    assert kf == autotune.cache_key("score", "tpu", 4, 64, 2048, 16, jnp.float32)
-    kb = autotune.cache_key("score", "tpu", 4, 64, 2048, 16, jnp.bfloat16)
+    kf = autotune.cache_key("score", TPU_V5E, 4, 64, 2048, 16)
+    assert kf == autotune.cache_key("score", TPU_V5E, 4, 64, 2048, 16, jnp.float32)
+    kb = autotune.cache_key("score", TPU_V5E, 4, 64, 2048, 16, jnp.bfloat16)
     assert kf != kb
     # Both entries coexist in the cache; the bf16 feasibility set is at
     # least as large (halved working set), so its pick is independent.
@@ -505,19 +534,19 @@ def test_validate_blocks_rejects_over_budget_pins():
 
     with pytest.raises(ValueError) as e:
         autotune.validate_blocks("score", block_n=256, block_cap=1024,
-                                 cap=2048, d=256, backend="tpu")
+                                 cap=2048, d=256, device_kind=TPU_V5E)
     msg = str(e.value)
     assert "block_n=256" in msg and "block_cap=1024" in msg
     assert "budget" in msg and "bytes" in msg
     # an in-budget pin passes through untouched
     assert autotune.validate_blocks(
-        "score", block_n=32, block_cap=128, cap=2048, d=256, backend="tpu"
+        "score", block_n=32, block_cap=128, cap=2048, d=256, device_kind=TPU_V5E
     ) == (32, 128)
     # block_cap >= cap routes resident: the pin is judged at the REAL
     # working set (lane-padded cap), so a nominal huge block_cap is fine
     # when the trajectory itself fits
     assert autotune.validate_blocks(
-        "score", block_n=32, block_cap=1 << 20, cap=256, d=16, backend="tpu"
+        "score", block_n=32, block_cap=1 << 20, cap=256, d=16, device_kind=TPU_V5E
     ) == (32, 1 << 20)
 
 

@@ -1,17 +1,58 @@
 """Launcher regression tests: launch/train.py resume-at-end and the
 launch/fedzoo.py CLI driven end-to-end on the quadratic objective."""
 
+import os
+import subprocess
 import sys
 
+import jax
 import pytest
 
+from repro.launch import compile_cache
 from repro.launch import fedzoo as fedzoo_launch
 from repro.launch import train as train_launch
+
+REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run_main(monkeypatch, module, argv):
     monkeypatch.setattr(sys, "argv", [f"{module.__name__}"] + argv)
+    # the entry point would turn on the checkout's persistent compile cache
+    # for the rest of this test process
+    monkeypatch.setattr(module, "enable_compile_cache", lambda: None, raising=False)
     module.main()
+
+
+def test_compile_cache_defaults_to_checkout_dir(monkeypatch):
+    """Unset JAX_COMPILATION_CACHE_DIR: the fixed <checkout>/.jax_cache."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+        assert got == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_honours_env_dir(tmp_path):
+    """Set JAX_COMPILATION_CACHE_DIR: entries land there and the helper sets
+    no other directory."""
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir)\n"
+        "jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((8, 8))).block_until_ready()\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=REPO_SRC + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [str(tmp_path)] * 2
+    assert os.listdir(tmp_path), "no cache entry written to JAX_COMPILATION_CACHE_DIR"
 
 
 TRAIN_ARGS = ["--arch", "qwen1_5_0_5b", "--variant", "smoke", "--steps", "2",

@@ -29,6 +29,7 @@ from repro.core import rff as rfflib
 from repro.core import rounds as rounds_mod
 from repro.core.federated import run_distributed
 from repro.faults import FaultConfig, corrupt
+from repro.launch.mesh import make_mesh
 
 ROUNDS = 8
 
@@ -57,7 +58,7 @@ def _sim(cfg, cobjs, rounds=ROUNDS, **kw):
 
 
 def _dist(cfg, cobjs, rounds=ROUNDS, **kw):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     return run_distributed(cfg, mesh, jax.random.PRNGKey(5), cobjs,
                            obj.quadratic_query, obj.quadratic_global_value,
                            rounds, **kw)

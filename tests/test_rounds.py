@@ -21,6 +21,7 @@ from repro.core import objectives as obj
 from repro.core import rounds as rounds_mod
 from repro.core.federated import run_distributed
 from repro.checkpoint import latest_step
+from repro.launch.mesh import make_mesh
 
 ROUNDS = 20
 
@@ -69,7 +70,7 @@ def test_scan_matches_loop_fzoos_sim(quad, fzoos_oracle):
 
 def test_scan_matches_loop_fzoos_distributed(quad, fzoos_oracle):
     """The shard_map engine scanning INSIDE shard_map vs the loop oracle."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     r_new = run_distributed(_fzoos_cfg(), mesh, jax.random.PRNGKey(5), quad,
                             obj.quadratic_query, obj.quadratic_global_value,
                             ROUNDS, chunk=8)
@@ -221,7 +222,7 @@ def test_eval_every_matches_loop_oracle(quad):
 def test_eval_every_distributed(quad):
     """eval_every through shard_map: the pmean inside the eval cond must
     lower and the NaN pattern must match the sim engine."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = _fzoos_cfg(local_steps=2)
     k = jax.random.PRNGKey(5)
     r = run_distributed(cfg, mesh, k, quad, obj.quadratic_query,

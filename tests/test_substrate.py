@@ -206,3 +206,19 @@ def test_spec_with_fallback_absent_axes_degrade_to_replicated():
     assert spec_with_fallback(m, (32, 64), (("model", "data"), None)) == P("data", None)
     assert spec_with_fallback(m, (33,), (("model", "data"),)) == P(None)  # 33 % 8
     assert spec_with_fallback(m, (32,), (("model", "pod"),)) == P(None)  # all absent
+
+
+@pytest.mark.parametrize("explicit", [False, True], ids=["auto", "explicit"])
+def test_constrain_valid_on_the_installed_mesh(explicit):
+    """``constrain`` is valid under jit on Auto meshes (``launch.mesh.make_mesh``)
+    and on Explicit ones (``jax.make_mesh``'s default), where it is a no-op."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh
+    from repro.sharding.rules import constrain, mesh_context
+
+    mesh = (jax.make_mesh((1,), ("data",), axis_types=(AxisType.Explicit,))
+            if explicit else make_mesh((1,), ("data",)))
+    x = jnp.arange(12.0).reshape(4, 3)
+    with mesh_context(mesh):
+        y = jax.jit(lambda a: constrain(a * 2, "data", None))(x)
+    np.testing.assert_array_equal(np.asarray(y), 2 * np.asarray(x))
