@@ -9,7 +9,7 @@ from hypothesis import settings
 
 jax.config.update("jax_enable_x64", False)
 
-# No on-disk example database: a run's examples depend only on committed
-# files, never on failures replayed from an earlier run's .hypothesis/.
+# No on-disk example database: no run replays failures saved in an earlier
+# run's .hypothesis/; counterexamples worth keeping are pinned with @example.
 settings.register_profile("repro", database=None)
 settings.load_profile("repro")
