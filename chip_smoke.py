@@ -106,7 +106,7 @@ def _norm_err(got, want) -> float:
 
 def phase_kernels(sz: Sizes, key) -> None:
     from repro.core import rff as rfflib
-    from repro.kernels import autotune, ops, ref
+    from repro.kernels import ops, ref
 
     n, d, nb, ls = sz.n_cand, sz.dim, sz.n_clients, 1.0
     prior = d / ls**2
@@ -155,25 +155,37 @@ def phase_kernels(sz: Sizes, key) -> None:
     cases.append(("sqexp", lambda: ops.sqexp(x1, x2, ls), lambda: ref.sqexp(x1, x2, ls),
                   2e-6, False, None))
 
-    for name, kern, oracle, tol, normalized, tuned in cases:
-        # the engine traces its round body with f32 matmuls; the oracle
-        # runs at the highest precision the chip has
-        with jax.default_matmul_precision("float32"):
-            got = jax.block_until_ready(kern())
-        with jax.default_matmul_precision("highest"):
-            want = jax.block_until_ready(oracle())
-        err = (_norm_err(got, want) if normalized
-               else float(np.abs(np.asarray(got) - np.asarray(want)).max()))
-        blocks = ""
-        if tuned is not None:
-            kind, nn, cap = tuned
-            bn, bc = autotune.select_blocks(kind, n=nn, cap=cap, d=d, n_clients=nb)
-            _check((bc < cap) == (cap > 128), f"{name}: tuner picked block_cap={bc}, "
-                   f"which does not route to the {'tiled' if cap > 128 else 'resident'} kernel")
-            blocks = f" blocks=({bn},{bc})"
-        print(f"  kernel {name}: shape={tuple(got.shape)}{blocks} err={err:.3e} tol={tol:.0e}")
-        _check(bool(np.isfinite(np.asarray(got)).all()), f"{name}: non-finite output")
-        _check(err <= tol, f"{name}: error {err:.3e} exceeds {tol:.0e}")
+    failed = []
+    for case in cases:
+        try:  # every kernel is checked, so one run reports all of them
+            _kernel_case(*case, d=d, nb=nb)
+        except Exception:
+            traceback.print_exc()
+            failed.append(case[0])
+    _check(not failed, f"kernels failed: {', '.join(failed)}")
+
+
+def _kernel_case(name, kern, oracle, tol, normalized, tuned, *, d, nb) -> None:
+    from repro.kernels import autotune
+
+    # the kernels fix their own matmul precision (spec.MXU_PRECISION);
+    # the oracle runs at the highest precision the chip has
+    got = jax.block_until_ready(kern())
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(oracle())
+    err = (_norm_err(got, want) if normalized
+           else float(np.abs(np.asarray(got) - np.asarray(want)).max()))
+    blocks = ""
+    if tuned is not None:
+        kind, nn, cap = tuned
+        bn, bc = autotune.select_blocks(kind, n=nn, cap=cap, d=d, n_clients=nb)
+        _check((bc < cap) == (cap > 128), f"{name}: tuner picked block_cap={bc}, "
+               f"which does not route to the {'tiled' if cap > 128 else 'resident'} kernel")
+        blocks = f" blocks=({bn},{bc})"
+    print(f"  kernel {name}: shape={tuple(got.shape)}{blocks} err={err:.3e} tol={tol:.0e}",
+          flush=True)
+    _check(bool(np.isfinite(np.asarray(got)).all()), f"{name}: non-finite output")
+    _check(err <= tol, f"{name}: error {err:.3e} exceeds {tol:.0e}")
 
 
 # ---------------------------------------------------------------------------

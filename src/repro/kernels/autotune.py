@@ -74,26 +74,40 @@ def clear_cache() -> None:
 
 
 def _vmem_cell_bytes(kind: str, bn: int, bc: int, d: int,
-                     itemsize: int = 4) -> int:
-    """Per-grid-cell VMEM working set, x2 for double buffering.
+                     itemsize: int = 4, *, resident: bool = False) -> int:
+    """Per-grid-cell VMEM working set: block buffers x2 for double
+    buffering, plus the f32 matmul's split operands.
 
-    score: c tile + two x tiles + two (bc, bc) Gram tiles + the h / cross /
-    g1 / g2 (bn, bc) intermediates + the (bn, 1) accumulator.
+    score: c tile + x tile(s) (two when cap-tiled, one when ``resident``)
+    + two (bc, bc) Gram tiles + the h / cross / g1 / g2 (bn, bc)
+    intermediates + the (bn, 1) accumulator.
     grad:  c tile + x tile + alpha row + the (bn, bc) w tile + the (bn, d)
     accumulator + the (bn, 1) running sum.
     ``itemsize`` is the element byte width of the caller's dtype (4 = the
     historical f32 assumption; the f32 accumulator scratch is charged at
     the same width, a deliberate over-estimate that keeps bf16 feasible
     sets conservative).
+
+    f32 operands add the bf16 split copies that the multi-pass f32 matmul
+    (``spec.MXU_PRECISION``) keeps in VMEM, held once.  Their coefficients
+    (per (bc, d) and (bn, d) f32 tile) make the total an upper bound of
+    what the v5e compiler allocated at d in {512, 3072}, block_n in
+    {8, ..., 128}, block_cap in {128, 256} (``tests/test_tpu_compile.py``
+    compiles every feasible pick at the engine's widths).
     """
     dl = _round_up(d, _LANE)  # minor axes are lane-padded by the compiler
     if kind == "score":
-        words = bn * dl + 2 * bc * dl + 2 * bc * bc + 5 * bn * bc + 2 * bn
+        x_tiles = 1 if resident else 2
+        words = bn * dl + x_tiles * bc * dl + 2 * bc * bc + 5 * bn * bc + 2 * bn
+        split_words = (x_tiles + 1) * bc * dl + 2 * bn * dl
     elif kind == "grad":
         words = bn * dl + bc * dl + bc + 3 * bn * bc + bn * dl + 2 * bn
+        split_words = 5 * bc * dl + 2 * bn * dl
     else:
         raise ValueError(f"unknown kernel kind {kind!r}")
-    return 2 * itemsize * words
+    if itemsize < 4:
+        split_words = 0  # sub-f32 operands feed the MXU as they are
+    return 2 * itemsize * words + 4 * split_words
 
 
 def _cell_cost(kind: str, bn: int, bc: int, d: int, hw: dict,
@@ -124,7 +138,8 @@ def _feasible(kind: str, n: int, cap: int, d: int, hw: dict,
         for bc in _BLOCK_CAP_CANDIDATES:
             if bc > _round_up(max(cap, 1), _LANE):
                 continue
-            if _vmem_cell_bytes(kind, bn, bc, d, itemsize) <= budget:
+            need = _vmem_cell_bytes(kind, bn, bc, d, itemsize, resident=bc >= cap)
+            if need <= budget:
                 yield bn, bc
 
 
@@ -153,7 +168,8 @@ def validate_blocks(
     # block_cap >= cap routes to the VMEM-resident kernel: the working set
     # is the lane-padded cap, not the nominal (possibly huge) pin.
     bc_eff = min(block_cap, _round_up(max(cap, 1), _LANE))
-    need = _vmem_cell_bytes(kind, block_n, bc_eff, d, itemsize)
+    need = _vmem_cell_bytes(kind, block_n, bc_eff, d, itemsize,
+                            resident=block_cap >= cap)
     if need > budget:
         raise ValueError(
             f"pinned {kind} blocks (block_n={block_n}, block_cap={block_cap})"

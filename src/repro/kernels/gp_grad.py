@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.spec import ArraySpec, BlockDecl, KernelSpec, ScratchDecl
+from repro.kernels.spec import MXU_PRECISION, ArraySpec, BlockDecl, KernelSpec, ScratchDecl
 
 
 def _grad_block(c, x, alpha, *, inv_two_l2: float, inv_l2: float):
@@ -52,12 +52,14 @@ def _grad_block(c, x, alpha, *, inv_two_l2: float, inv_l2: float):
     n1 = jnp.sum(c * c, axis=-1, keepdims=True)  # (bn, 1)
     n2 = jnp.sum(x * x, axis=-1, keepdims=True).T  # (1, cap)
     cross = jax.lax.dot_general(
-        c, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        c, x, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     d2 = jnp.maximum(n1 + n2 - 2.0 * cross, 0.0)
     w = jnp.exp(-d2 * inv_two_l2) * alpha  # (bn, cap), alpha row-broadcast
     acc = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        w, x, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )  # (bn, d)
     s = jnp.sum(w, axis=-1, keepdims=True)
     return (acc - s * c) * inv_l2
@@ -178,12 +180,14 @@ def _grad_cell(c, x, alpha, acc_ref, s_ref, *, inv_two_l2: float):
     n1 = jnp.sum(c * c, axis=-1, keepdims=True)  # (bn, 1)
     n2 = jnp.sum(x * x, axis=-1, keepdims=True).T  # (1, bc)
     cross = jax.lax.dot_general(
-        c, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        c, x, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     d2 = jnp.maximum(n1 + n2 - 2.0 * cross, 0.0)
     w = jnp.exp(-d2 * inv_two_l2) * alpha  # (bn, bc)
     acc_ref[...] += jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        w, x, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     ).astype(jnp.float32)
     s_ref[...] += jnp.sum(w, axis=-1, keepdims=True).astype(jnp.float32)
 

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.spec import ArraySpec, BlockDecl, KernelSpec, ScratchDecl
+from repro.kernels.spec import MXU_PRECISION, ArraySpec, BlockDecl, KernelSpec, ScratchDecl
 
 
 def _h_tile(c, n1, x, inv_two_l2: float):
@@ -62,7 +62,8 @@ def _h_tile(c, n1, x, inv_two_l2: float):
     x (bc, d) -> (h (bn, bc), cross (bn, bc))."""
     n2 = jnp.sum(x * x, axis=-1, keepdims=True).T  # (1, bc)
     cross = jax.lax.dot_general(
-        c, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        c, x, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     d2 = jnp.maximum(n1 + n2 - 2.0 * cross, 0.0)
     return jnp.exp(-d2 * inv_two_l2), cross
@@ -74,10 +75,12 @@ def _score_block(c, x, binv, pmat, *, inv_two_l2: float, inv_l4: float, prior: f
     n1 = jnp.sum(c * c, axis=-1, keepdims=True)  # (bn, 1)
     h, cross = _h_tile(c, n1, x, inv_two_l2)
     g1 = jax.lax.dot_general(
-        h, pmat, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        h, pmat, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     g2 = jax.lax.dot_general(
-        h, binv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        h, binv, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     corr = jnp.sum((g1 - (2.0 * cross - n1) * g2) * h, axis=-1, keepdims=True) * inv_l4
     return jnp.maximum(prior - corr, 0.0)
@@ -228,10 +231,12 @@ def _score_cell(c, xj, xk, b, p, acc_ref, *, inv_two_l2: float):
     hj, _ = _h_tile(c, n1, xj, inv_two_l2)
     hk, cross_k = _h_tile(c, n1, xk, inv_two_l2)
     g1 = jax.lax.dot_general(
-        hj, p, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        hj, p, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     g2 = jax.lax.dot_general(
-        hj, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        hj, b, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     contrib = jnp.sum((g1 - (2.0 * cross_k - n1) * g2) * hk, axis=-1, keepdims=True)
     acc_ref[...] += contrib.astype(jnp.float32)

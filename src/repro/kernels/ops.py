@@ -141,7 +141,7 @@ def rff_grad(
     w: jax.Array,
     *,
     block_n: int = 128,
-    block_m: int = 256,
+    block_m: int = 128,  # 256 overruns the 16 MiB scoped VMEM of a v5e at d=3072
     force_pallas: bool = False,
 ) -> jax.Array:
     """grad phi(X)^T w: (n,d),(M,d),(M,),(M,) -> (n,d)."""

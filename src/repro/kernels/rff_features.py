@@ -21,7 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.spec import ArraySpec, BlockDecl, KernelSpec
+from repro.kernels.spec import MXU_PRECISION, ArraySpec, BlockDecl, KernelSpec
 
 
 def _kernel(x_ref, v_ref, b_ref, o_ref, *, scale: float):
@@ -29,7 +29,8 @@ def _kernel(x_ref, v_ref, b_ref, o_ref, *, scale: float):
     v = v_ref[...]  # (bm, d)
     b = b_ref[...]  # (1, bm)
     proj = jax.lax.dot_general(
-        x, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, v, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )  # (bn, bm)
     o_ref[...] = (scale * jnp.cos(proj + b)).astype(o_ref.dtype)
 

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.spec import ArraySpec, BlockDecl, KernelSpec
+from repro.kernels.spec import MXU_PRECISION, ArraySpec, BlockDecl, KernelSpec
 
 
 def _kernel(x_ref, v_ref, b_ref, w_ref, o_ref, *, scale: float):
@@ -32,11 +32,13 @@ def _kernel(x_ref, v_ref, b_ref, w_ref, o_ref, *, scale: float):
     b = b_ref[...]  # (1, bm)
     w = w_ref[...]  # (1, bm)
     proj = jax.lax.dot_general(
-        x, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, v, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )  # (bn, bm)
     s = jnp.sin(proj + b) * w  # (bn, bm)
     contrib = -scale * jax.lax.dot_general(
-        s, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        s, v, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )  # (bn, d)
 
     @pl.when(j == 0)
@@ -57,7 +59,7 @@ def rff_grad_kernel(
     *,
     n_features: int,
     block_n: int = 128,
-    block_m: int = 256,
+    block_m: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """x (n,d), v (M,d), b (M,), w (M,) -> (n,d).  Block-aligned inputs;

@@ -51,6 +51,14 @@ from jax.experimental.pallas import tpu as pltpu
 _SUBLANE = 8
 _LANE = 128
 
+#: Precision of every matmul inside the kernels.  On a TPU an f32 matmul at
+#: the default precision is one bf16 pass, too coarse for the kernels'
+#: parity tolerances at d in the thousands, so the kernels ask for f32
+#: accuracy and never inherit the caller's ``default_matmul_precision``.
+#: The multi-pass f32 matmul splits its f32 operands into bf16 parts held in
+#: VMEM; ``repro.kernels.autotune`` charges those copies.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m

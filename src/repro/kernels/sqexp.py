@@ -14,7 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.spec import ArraySpec, BlockDecl, KernelSpec
+from repro.kernels.spec import MXU_PRECISION, ArraySpec, BlockDecl, KernelSpec
 
 
 def _kernel(x1_ref, x2_ref, o_ref, *, inv_two_l2: float):
@@ -23,7 +23,8 @@ def _kernel(x1_ref, x2_ref, o_ref, *, inv_two_l2: float):
     n1 = jnp.sum(x1 * x1, axis=-1, keepdims=True)  # (bn, 1)
     n2 = jnp.sum(x2 * x2, axis=-1, keepdims=True).T  # (1, bm)
     cross = jax.lax.dot_general(
-        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x1, x2, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32
     )
     d2 = jnp.maximum(n1 + n2 - 2.0 * cross, 0.0)
     o_ref[...] = jnp.exp(-d2 * inv_two_l2).astype(o_ref.dtype)

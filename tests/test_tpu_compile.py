@@ -30,7 +30,7 @@ from repro.kernels.gp_score import (
 from repro.kernels.rff_features import rff_features_kernel
 from repro.kernels.rff_grad import rff_grad_kernel
 from repro.kernels.sqexp import sqexp_kernel
-from repro.launch.mesh import TPU_V5E
+from repro.launch.mesh import TPU_V5E, roofline
 
 N, N_CAND, D, M = 64, 128, 3072, 512
 LENGTHSCALE = 1.0  # the AlgoConfig default
@@ -71,28 +71,33 @@ def _blocks(kind, cap, *, tiled):
     return bn, bc
 
 
+def _lower_gp(kind, sh, *, n, cap, block_n, block_cap):
+    """Lower the GP kernel that ``kernels/ops.py`` routes these blocks to:
+    cap-tiled when ``block_cap < cap``, VMEM-resident otherwise."""
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sh)
+    tiled = block_cap < cap
+    if kind == "score":
+        args = (f32(N, n, D), f32(N, cap, D), f32(N, cap, cap), f32(N, cap, cap))
+        kw = dict(lengthscale=LENGTHSCALE, prior=D / LENGTHSCALE**2, block_n=block_n)
+        if tiled:
+            return uncertainty_scores_tiled_clients_kernel.lower(*args, **kw,
+                                                                 block_cap=block_cap)
+        return uncertainty_scores_clients_kernel.lower(*args, **kw)
+    args = (f32(N, n, D), f32(N, cap, D), f32(N, 1, cap))
+    kw = dict(lengthscale=LENGTHSCALE, block_n=block_n)
+    if tiled:
+        return grad_mean_tiled_clients_kernel.lower(*args, **kw, block_cap=block_cap)
+    return grad_mean_clients_kernel.lower(*args, **kw)
+
+
 def _lower_kernel(name, sh):
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sh)
-    prior = D / LENGTHSCALE**2
-    if name in ("scores-clients", "scores-tiled-clients"):
-        tiled = name == "scores-tiled-clients"
+    if name in ("scores-clients", "scores-tiled-clients", "grad-clients", "grad-tiled-clients"):
+        kind = "score" if name.startswith("scores") else "grad"
+        tiled = "tiled" in name
         cap = 1024 if tiled else 128
-        bn, bc = _blocks("score", cap, tiled=tiled)
-        args = (f32(N, N_CAND, D), f32(N, cap, D), f32(N, cap, cap), f32(N, cap, cap))
-        if tiled:
-            return uncertainty_scores_tiled_clients_kernel.lower(
-                *args, lengthscale=LENGTHSCALE, prior=prior, block_n=bn, block_cap=bc)
-        return uncertainty_scores_clients_kernel.lower(
-            *args, lengthscale=LENGTHSCALE, prior=prior, block_n=bn)
-    if name in ("grad-clients", "grad-tiled-clients"):
-        tiled = name == "grad-tiled-clients"
-        cap = 1024 if tiled else 128
-        bn, bc = _blocks("grad", cap, tiled=tiled)
-        args = (f32(N, N_CAND, D), f32(N, cap, D), f32(N, 1, cap))
-        if tiled:
-            return grad_mean_tiled_clients_kernel.lower(
-                *args, lengthscale=LENGTHSCALE, block_n=bn, block_cap=bc)
-        return grad_mean_clients_kernel.lower(*args, lengthscale=LENGTHSCALE, block_n=bn)
+        bn, bc = _blocks(kind, cap, tiled=tiled)
+        return _lower_gp(kind, sh, n=N_CAND, cap=cap, block_n=bn, block_cap=bc)
     if name == "rff-features":
         return rff_features_kernel.lower(f32(N_CAND, D), f32(M, D), f32(M), n_features=M)
     if name == "rff-grad":
@@ -109,6 +114,20 @@ def _lower_kernel(name, sh):
 def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = _lower_kernel(name, one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind,cap", [("score", 128), ("score", 1024),
+                                      ("grad", 128), ("grad", 1024)])
+def test_every_feasible_block_pick_compiles_for_v5e(one_chip, kind, cap):
+    """The tuner's VMEM model bounds what the compiler allocates: every
+    ``(block_n, block_cap)`` it may pick at d=3072 compiles within the
+    chip's default scoped VMEM.  (The engine's grad mean runs one point per
+    client, block_n=8; a model without the f32 matmul's split operands let
+    block_cap=256 through there, 18.5 MiB against a 16 MiB limit.)"""
+    picks = list(autotune._feasible(kind, N_CAND, cap, D, roofline(TPU_V5E)))
+    assert picks
+    for bn, bc in picks:
+        _lower_gp(kind, one_chip, n=bn, cap=cap, block_n=bn, block_cap=bc).compile()
 
 
 def test_fzoos_chunk_step_takes_the_kernel_route_on_v5e(one_chip, monkeypatch):
