@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import threading
 from functools import lru_cache
 from typing import Callable, Optional
 
@@ -56,17 +55,11 @@ import jax.numpy as jnp
 
 from repro.analysis import hlo_audit, jaxpr_lint
 from repro.analysis.jaxpr_lint import Violation
+from repro.core import telemetry
 
 # ---------------------------------------------------------------------------
 # Steady-state guard (recompiles + host syncs)
 # ---------------------------------------------------------------------------
-
-#: Monitoring event jax records once per backend executable compile.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-_guard_lock = threading.Lock()
-_active_guards: list["GuardState"] = []
-_listener_installed = False
 
 
 class SteadyStateViolation(AssertionError):
@@ -75,25 +68,24 @@ class SteadyStateViolation(AssertionError):
 
 @dataclasses.dataclass
 class GuardState:
-    """Counters exposed to the ``with steady_state_guard() as g`` body."""
+    """Counters exposed to the ``with steady_state_guard() as g`` body: the
+    compiles and ``jax.device_get`` calls since the guard was entered, read
+    from the process counters of ``core.telemetry`` (frozen at exit)."""
 
-    compiles: int = 0
-    device_gets: int = 0
+    start: dict
+    end: Optional[dict] = None
 
+    def _since_start(self, name: str) -> int:
+        now = self.end if self.end is not None else telemetry.counters()
+        return now[name] - self.start[name]
 
-def _on_event_duration(event: str, duration: float, **kw) -> None:
-    del duration, kw
-    if event == _COMPILE_EVENT:
-        with _guard_lock:
-            for g in _active_guards:
-                g.compiles += 1
+    @property
+    def compiles(self) -> int:
+        return self._since_start("compiles")
 
-
-def _ensure_listener() -> None:
-    global _listener_installed
-    if not _listener_installed:
-        jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _listener_installed = True
+    @property
+    def device_gets(self) -> int:
+        return self._since_start("device_gets")
 
 
 @contextlib.contextmanager
@@ -110,23 +102,12 @@ def steady_state_guard(
     class PR 4 eliminated).  Yields a ``GuardState`` whose counters are
     live, so callers can also assert richer conditions themselves.
     """
-    _ensure_listener()
-    st = GuardState()
-    real_get = jax.device_get
-
-    def spy(x):
-        st.device_gets += 1
-        return real_get(x)
-
-    with _guard_lock:
-        _active_guards.append(st)
-    jax.device_get = spy
+    st = GuardState(telemetry.counters())
     try:
-        yield st
+        with telemetry.counting_device_gets():
+            yield st
     finally:
-        jax.device_get = real_get
-        with _guard_lock:
-            _active_guards.remove(st)
+        st.end = telemetry.counters()
     if allow_compiles is not None and st.compiles > allow_compiles:
         raise SteadyStateViolation(
             f"steady-state window compiled {st.compiles} executable(s) "

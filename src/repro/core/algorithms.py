@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.core import fd as fdlib
 from repro.core import gp_surrogate as gp
 from repro.core import rff as rfflib
+from repro.core import telemetry
 from repro.optim import make_optimizer
 
 Pytree = Any
@@ -280,37 +281,41 @@ def _local_phase(
             # Trajectory-informed: query the current iterate (+ active queries)
             # BEFORE estimating -- the estimate is conditioned on D_{r,t-1}.
             hyper = _hyper_of(cfg)
-            y = query_fn(cobj, st.x, k_obs)
-            if cfg.use_factor_cache:
-                traj, factor = gp.traj_extend(
-                    st.traj, st.factor, st.x[None, :], y[None], hyper
-                )
-            else:
-                traj, factor = gp.traj_append(st.traj, st.x, y), st.factor
+            with telemetry.scope("fzoo.local.update"):
+                y = query_fn(cobj, st.x, k_obs)
+                if cfg.use_factor_cache:
+                    traj, factor = gp.traj_extend(
+                        st.traj, st.factor, st.x[None, :], y[None], hyper
+                    )
+                else:
+                    traj, factor = gp.traj_append(st.traj, st.x, y), st.factor
             n_q = 1
             if cfg.active_per_iter > 0:
-                if cfg.use_factor_cache:
-                    cands = gp.select_active_queries_cached(
-                        k_act, traj, factor, hyper, st.x, cfg.active_candidates,
-                        cfg.active_per_iter, cfg.active_radius, cfg.lo, cfg.hi,
-                    )
-                else:
-                    cands = gp.select_active_queries(
-                        k_act, traj, hyper, st.x, cfg.active_candidates, cfg.active_per_iter,
-                        cfg.active_radius, cfg.lo, cfg.hi,
-                    )
-                # key-flow: ok (k_act sample/fold streams audited; kept for
-                # bitwise seed-replay compatibility)
-                kq = jax.random.split(jax.random.fold_in(k_act, 1), cfg.active_per_iter)
-                ys = jax.vmap(lambda c, k: query_fn(cobj, c, k))(cands, kq)
-                if cfg.use_factor_cache:
-                    traj, factor = gp.traj_extend(traj, factor, cands, ys, hyper)
-                else:
-                    traj = gp.traj_append_batch(traj, cands, ys)
+                with telemetry.scope("fzoo.local.score"):
+                    if cfg.use_factor_cache:
+                        cands = gp.select_active_queries_cached(
+                            k_act, traj, factor, hyper, st.x, cfg.active_candidates,
+                            cfg.active_per_iter, cfg.active_radius, cfg.lo, cfg.hi,
+                        )
+                    else:
+                        cands = gp.select_active_queries(
+                            k_act, traj, hyper, st.x, cfg.active_candidates,
+                            cfg.active_per_iter, cfg.active_radius, cfg.lo, cfg.hi,
+                        )
+                with telemetry.scope("fzoo.local.update"):
+                    # key-flow: ok (k_act sample/fold streams audited; kept for
+                    # bitwise seed-replay compatibility)
+                    kq = jax.random.split(jax.random.fold_in(k_act, 1), cfg.active_per_iter)
+                    ys = jax.vmap(lambda c, k: query_fn(cobj, c, k))(cands, kq)
+                    if cfg.use_factor_cache:
+                        traj, factor = gp.traj_extend(traj, factor, cands, ys, hyper)
+                    else:
+                        traj = gp.traj_append_batch(traj, cands, ys)
                 n_q += cfg.active_per_iter
             st = st._replace(traj=traj, factor=factor, queries=st.queries + n_q)
 
-        ghat, st = _estimate_gradient(cfg, rff, query_fn, cobj, st, server_x, t, k_est)
+        with telemetry.scope("fzoo.local.grad"):
+            ghat, st = _estimate_gradient(cfg, rff, query_fn, cobj, st, server_x, t, k_est)
         new_x, new_opt = opt_update(st.opt, ghat, st.x, cfg.eta)
         new_x = jnp.clip(new_x, cfg.lo, cfg.hi)
 
@@ -361,41 +366,46 @@ def _local_phase_clients(
         sts = sts._replace(key=ks[:, 0])
         k_act = ks[:, 2]
 
-        y = jax.vmap(query_fn)(cobjs, sts.x, ks[:, 1])
-        traj, factor = gp.traj_extend_clients(
-            sts.traj, sts.factor, sts.x[:, None, :], y[:, None], hyper, deferred=True
-        )
+        with telemetry.scope("fzoo.local.update"):
+            y = jax.vmap(query_fn)(cobjs, sts.x, ks[:, 1])
+            traj, factor = gp.traj_extend_clients(
+                sts.traj, sts.factor, sts.x[:, None, :], y[:, None], hyper, deferred=True
+            )
         n_q = 1
         if cfg.active_per_iter > 0:
-            cands = gp.select_active_queries_cached_clients(
-                k_act, traj, factor, hyper, sts.x, cfg.active_candidates,
-                cfg.active_per_iter, cfg.active_radius, cfg.lo, cfg.hi,
-                block_n=cfg.score_block_n, block_cap=cfg.score_block_cap,
-            )  # (N, n_act, d)
-            kq = jax.vmap(
-                # key-flow: ok (k_act sample/fold streams audited; kept for
-                # bitwise seed-replay compatibility)
-                lambda k: jax.random.split(jax.random.fold_in(k, 1), cfg.active_per_iter)
-            )(k_act)
-            ys = jax.vmap(
-                lambda cobj, cs, kk: jax.vmap(lambda c, k: query_fn(cobj, c, k))(cs, kk)
-            )(cobjs, cands, kq)
-            traj, factor = gp.traj_extend_clients(traj, factor, cands, ys, hyper, deferred=True)
+            with telemetry.scope("fzoo.local.score"):
+                cands = gp.select_active_queries_cached_clients(
+                    k_act, traj, factor, hyper, sts.x, cfg.active_candidates,
+                    cfg.active_per_iter, cfg.active_radius, cfg.lo, cfg.hi,
+                    block_n=cfg.score_block_n, block_cap=cfg.score_block_cap,
+                )  # (N, n_act, d)
+            with telemetry.scope("fzoo.local.update"):
+                kq = jax.vmap(
+                    # key-flow: ok (k_act sample/fold streams audited; kept for
+                    # bitwise seed-replay compatibility)
+                    lambda k: jax.random.split(jax.random.fold_in(k, 1), cfg.active_per_iter)
+                )(k_act)
+                ys = jax.vmap(
+                    lambda cobj, cs, kk: jax.vmap(lambda c, k: query_fn(cobj, c, k))(cs, kk)
+                )(cobjs, cands, kq)
+                traj, factor = gp.traj_extend_clients(traj, factor, cands, ys, hyper,
+                                                      deferred=True)
             n_q += cfg.active_per_iter
         sts = sts._replace(traj=traj, factor=factor, queries=sts.queries + n_q)
 
         # eq. (2): batched surrogate mean + per-client RFF correction
-        g_loc = gp.grad_mean_cached_clients(
-            traj, factor, hyper, sts.x,
-            block_n=cfg.grad_block_n, block_cap=cfg.grad_block_cap,
-        )  # (N, d)
-        corr = rfflib.grad_features_t_w_rows(rff, sts.x, sts.w_global) - \
-            rfflib.grad_features_t_w_rows(rff, sts.x, sts.w_local)
-        if cfg.gamma_mode == "inv_t":
-            gamma = 1.0 / t.astype(jnp.float32)
-        else:
-            gamma = jnp.asarray(cfg.gamma_const, jnp.float32)
-        ghat = g_loc + gamma * corr
+        with telemetry.scope("fzoo.local.grad"):
+            g_loc = gp.grad_mean_cached_clients(
+                traj, factor, hyper, sts.x,
+                block_n=cfg.grad_block_n, block_cap=cfg.grad_block_cap,
+            )  # (N, d)
+            corr = rfflib.grad_features_t_w_rows(rff, sts.x, sts.w_global) - \
+                rfflib.grad_features_t_w_rows(rff, sts.x, sts.w_local)
+            if cfg.gamma_mode == "inv_t":
+                gamma = 1.0 / t.astype(jnp.float32)
+            else:
+                gamma = jnp.asarray(cfg.gamma_const, jnp.float32)
+            ghat = g_loc + gamma * corr
 
         new_x, new_opt = jax.vmap(lambda o, g, x: opt_update(o, g, x, cfg.eta))(
             sts.opt, ghat, sts.x
@@ -549,55 +559,57 @@ def run_round(
     states0 = states if faults is not None else None
 
     # ---- T local steps on every client in parallel ----
-    if cfg.deferred:
-        # Deferred-repair engine: branch-free factor updates, client-batched
-        # surrogate kernels (one launch per step for the whole batch).
-        states, sum_cos, sum_disp = _local_phase_clients(
-            cfg, rff, query_fn, cobjs, states, diag_global_grad
-        )
-    else:
-        local = partial(_local_phase, cfg, rff, query_fn)
-        states, sum_cos, sum_disp = jax.vmap(
-            lambda cobj, st: local(cobj, st, server_x, diag_global_grad)
-        )(cobjs, states)
+    with telemetry.scope("fzoo.local"):
+        if cfg.deferred:
+            # Deferred-repair engine: branch-free factor updates, client-batched
+            # surrogate kernels (one launch per step for the whole batch).
+            states, sum_cos, sum_disp = _local_phase_clients(
+                cfg, rff, query_fn, cobjs, states, diag_global_grad
+            )
+        else:
+            local = partial(_local_phase, cfg, rff, query_fn)
+            states, sum_cos, sum_disp = jax.vmap(
+                lambda cobj, st: local(cobj, st, server_x, diag_global_grad)
+            )(cobjs, states)
 
     # ---- server aggregation of the iterates (line 7/9 of Algo. 1/2) ----
     zero = jnp.zeros((), jnp.float32)
     live = quar = n_live = n_quar = None
-    if faults is None:
-        new_server_x = mean_fn(states.x)
-    else:
-        # Inject the payload faults on the UPDATE, never on the state: the
-        # client's own state stays finite and is rolled back below.
-        x_up = states.x
-        if faults.nan_rate > 0:
-            x_up = jnp.where(draws.nan[:, None], jnp.float32(jnp.nan), x_up)
-        if faults.inf_rate > 0:
-            x_up = jnp.where(draws.inf[:, None], jnp.float32(jnp.inf), x_up)
-        # straggler: the server sees its STALE iterate (this round's broadcast)
-        x_up = jnp.where(draws.straggle[:, None], server_x, x_up)
-        if faults.tolerate:
-            # On-device liveness + health mask.  NOTE: jnp.where, never
-            # multiply-by-mask -- NaN * 0 is NaN and would defeat the mask.
-            finite = jnp.all(jnp.isfinite(x_up), axis=-1)
-            quar = states.quarantined | (~finite & ~draws.drop)
-            live = ~draws.drop & ~states.quarantined & finite
-            # The live count and quarantine census ride as two extra rows of
-            # the SAME psum operand: masking adds zero collectives.
-            payload = jnp.concatenate(
-                [jnp.where(live[:, None], x_up, 0.0),
-                 live.astype(jnp.float32)[:, None],
-                 quar.astype(jnp.float32)[:, None]], axis=1)
-            tot = sum_fn(payload)
-            n_live, n_quar = tot[cfg.dim], tot[cfg.dim + 1]
-            new_server_x = jnp.where(
-                n_live > 0, tot[: cfg.dim] / jnp.maximum(n_live, 1.0), server_x)
+    with telemetry.scope("fzoo.aggregate"):
+        if faults is None:
+            new_server_x = mean_fn(states.x)
         else:
-            # No tolerance: a dropped client is simply never heard from, and
-            # the dense mean treats silence as NaN -- the poisoning failure
-            # mode the masked path removes (and the rollback demo trigger).
-            x_up = jnp.where(draws.drop[:, None], jnp.float32(jnp.nan), x_up)
-            new_server_x = sum_fn(x_up) / cfg.n_clients
+            # Inject the payload faults on the UPDATE, never on the state: the
+            # client's own state stays finite and is rolled back below.
+            x_up = states.x
+            if faults.nan_rate > 0:
+                x_up = jnp.where(draws.nan[:, None], jnp.float32(jnp.nan), x_up)
+            if faults.inf_rate > 0:
+                x_up = jnp.where(draws.inf[:, None], jnp.float32(jnp.inf), x_up)
+            # straggler: the server sees its STALE iterate (this round's broadcast)
+            x_up = jnp.where(draws.straggle[:, None], server_x, x_up)
+            if faults.tolerate:
+                # On-device liveness + health mask.  NOTE: jnp.where, never
+                # multiply-by-mask -- NaN * 0 is NaN and would defeat the mask.
+                finite = jnp.all(jnp.isfinite(x_up), axis=-1)
+                quar = states.quarantined | (~finite & ~draws.drop)
+                live = ~draws.drop & ~states.quarantined & finite
+                # The live count and quarantine census ride as two extra rows of
+                # the SAME psum operand: masking adds zero collectives.
+                payload = jnp.concatenate(
+                    [jnp.where(live[:, None], x_up, 0.0),
+                     live.astype(jnp.float32)[:, None],
+                     quar.astype(jnp.float32)[:, None]], axis=1)
+                tot = sum_fn(payload)
+                n_live, n_quar = tot[cfg.dim], tot[cfg.dim + 1]
+                new_server_x = jnp.where(
+                    n_live > 0, tot[: cfg.dim] / jnp.maximum(n_live, 1.0), server_x)
+            else:
+                # No tolerance: a dropped client is simply never heard from, and
+                # the dense mean treats silence as NaN -- the poisoning failure
+                # mode the masked path removes (and the rollback demo trigger).
+                x_up = jnp.where(draws.drop[:, None], jnp.float32(jnp.nan), x_up)
+                new_server_x = sum_fn(x_up) / cfg.n_clients
 
     # ---- post phase ----
     def post(st: ClientState, cobj) -> ClientState:
@@ -640,10 +652,11 @@ def run_round(
             st = st._replace(c_local=st.fd_accum / cfg.local_steps)
         return st
 
-    if cfg.deferred:
-        states = _post_phase_clients(cfg, rff, query_fn, cobjs, states, new_server_x)
-    else:
-        states = jax.vmap(post)(states, cobjs)
+    with telemetry.scope("fzoo.round_end"):
+        if cfg.deferred:
+            states = _post_phase_clients(cfg, rff, query_fn, cobjs, states, new_server_x)
+        else:
+            states = jax.vmap(post)(states, cobjs)
 
     # ---- fault response: roll faulted clients back to the round prologue ----
     if faults is not None and faults.tolerate:
@@ -660,26 +673,27 @@ def run_round(
         states = states._replace(quarantined=quar)
 
     # ---- second aggregation: w (FZooS) / control variates (scaffold2) ----
-    if cfg.is_fzoos:
-        if faults is not None and faults.tolerate:
-            # stragglers contribute their (stale) w; quarantined and dropped
-            # clients are masked out, count packed into the same psum operand
-            m_w = ~draws.drop & ~quar
-            w_pay = jnp.concatenate(
-                [jnp.where(m_w[:, None], states.w_local, 0.0),
-                 m_w.astype(jnp.float32)[:, None]], axis=1)
-            w_tot = sum_fn(w_pay)
-            w_glob = jnp.where(
-                w_tot[-1] > 0, w_tot[:-1] / jnp.maximum(w_tot[-1], 1.0),
-                # all clients dead: keep the previous global w (replicated
-                # rows, so the LOCAL mean is the global value -- no psum)
-                jnp.mean(states.w_global, axis=0))
-        else:
-            w_glob = mean_fn(states.w_local)
-        states = states._replace(w_global=jnp.broadcast_to(w_glob, states.w_global.shape))
-    elif cfg.name == "scaffold2":
-        c_glob = mean_fn(states.c_local)
-        states = states._replace(c_global=jnp.broadcast_to(c_glob, states.c_global.shape))
+    with telemetry.scope("fzoo.aggregate"):
+        if cfg.is_fzoos:
+            if faults is not None and faults.tolerate:
+                # stragglers contribute their (stale) w; quarantined and dropped
+                # clients are masked out, count packed into the same psum operand
+                m_w = ~draws.drop & ~quar
+                w_pay = jnp.concatenate(
+                    [jnp.where(m_w[:, None], states.w_local, 0.0),
+                     m_w.astype(jnp.float32)[:, None]], axis=1)
+                w_tot = sum_fn(w_pay)
+                w_glob = jnp.where(
+                    w_tot[-1] > 0, w_tot[:-1] / jnp.maximum(w_tot[-1], 1.0),
+                    # all clients dead: keep the previous global w (replicated
+                    # rows, so the LOCAL mean is the global value -- no psum)
+                    jnp.mean(states.w_global, axis=0))
+            else:
+                w_glob = mean_fn(states.w_local)
+            states = states._replace(w_global=jnp.broadcast_to(w_glob, states.w_global.shape))
+        elif cfg.name == "scaffold2":
+            c_glob = mean_fn(states.c_local)
+            states = states._replace(c_global=jnp.broadcast_to(c_glob, states.c_global.shape))
 
     # ---- round stats (masked means over live clients under faults) ----
     if faults is None:
@@ -814,119 +828,124 @@ def simulate(
         raise ValueError(f"chunk must be None, 0 (loop oracle) or positive, got {chunk}")
     if eval_every < 1:
         raise ValueError(f"eval_every must be >= 1, got {eval_every}")
-    if x0 is None:
-        x0 = jnp.full((cfg.dim,), 0.5, jnp.float32)
-    k_init, k_rff, k_rounds = jax.random.split(key, 3)
-    rff = None
-    if cfg.is_fzoos:
-        rff = rfflib.make_rff(rff_key if rff_key is not None else k_rff, cfg.n_features, cfg.dim, cfg.lengthscale)
+    if cohort is not None and chunk == 0:
+        raise ValueError("cohort (partial participation) requires the "
+                         "scan driver (chunk != 0); the dense engine at "
+                         "cohort == n_clients is the equivalence oracle")
+    with telemetry.span("fzoo.simulate", algo=cfg.name, rounds=rounds, chunk=chunk):
+        with telemetry.span("fzoo.init"):
+            if x0 is None:
+                x0 = jnp.full((cfg.dim,), 0.5, jnp.float32)
+            k_init, k_rff, k_rounds = jax.random.split(key, 3)
+            rff = None
+            if cfg.is_fzoos:
+                rff = rfflib.make_rff(rff_key if rff_key is not None else k_rff,
+                                      cfg.n_features, cfg.dim, cfg.lengthscale)
+            if cohort is None:
+                states = init_states(cfg, k_init, x0)
+            else:
+                from repro.core import pool as pool_mod  # deferred: avoids cycle
 
-    if cohort is not None:
-        if chunk == 0:
-            raise ValueError("cohort (partial participation) requires the "
-                             "scan driver (chunk != 0); the dense engine at "
-                             "cohort == n_clients is the equivalence oracle")
-        from repro.core import pool as pool_mod  # deferred: avoids cycle
-        from repro.core import rounds as rounds_mod
+                pool = pool_mod.init_pool(cfg, k_init, x0)
 
-        pool = pool_mod.init_pool(cfg, k_init, x0)
-        _, res = pool_mod.run_pooled_rounds(
-            cfg, rff, query_fn, cobjs, pool, x0, global_value_fn,
-            rounds, chunk if chunk is not None else rounds_mod.DEFAULT_CHUNK,
-            cohort=cohort, cohort_seed=cohort_seed,
-            diag_global_grad=diag_global_grad,
-            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-            eval_every=eval_every, async_checkpoint=async_checkpoint,
-            faults=faults, max_rollbacks=max_rollbacks,
-        )
-        return res
+        if cohort is not None:
+            from repro.core import rounds as rounds_mod
 
-    states = init_states(cfg, k_init, x0)
-
-    if chunk is None or chunk > 0:
-        from repro.core import rounds as rounds_mod  # deferred: avoids cycle
-
-        if chunk is None:
-            chunk = rounds_mod.DEFAULT_CHUNK
-        _, res = rounds_mod.run_rounds(
-            cfg, rff, query_fn, cobjs, states, x0, global_value_fn,
-            rounds, chunk, diag_global_grad=diag_global_grad,
-            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-            eval_every=eval_every, async_checkpoint=async_checkpoint,
-            faults=faults, max_rollbacks=max_rollbacks,
-        )
-        return res
-
-    if checkpoint_dir:
-        raise ValueError("checkpoint_dir requires the scan driver (chunk != 0)")
-    if faults is not None:
-        # Loop oracle matches the scan engine: a never-active window runs
-        # the faults-free body (see rounds.run_rounds).
-        from repro.faults.injector import effective_config
-        faults = effective_config(faults, rounds)
-    mean_fn = lambda tree: jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), tree)
-
-    if faults is None:
-        round_jit = jax.jit(
-            lambda states, sx: run_round(cfg, rff, query_fn, cobjs, states, sx, mean_fn, diag_global_grad)
-        )
-    else:
-        sum_fn = lambda a: jnp.sum(a, axis=0)
-        round_jit = jax.jit(
-            lambda states, sx, r: run_round(
-                cfg, rff, query_fn, cobjs, states, sx, mean_fn, diag_global_grad,
-                sum_fn=sum_fn, faults=faults, round_idx=r,
+            _, res = pool_mod.run_pooled_rounds(
+                cfg, rff, query_fn, cobjs, pool, x0, global_value_fn,
+                rounds, chunk if chunk is not None else rounds_mod.DEFAULT_CHUNK,
+                cohort=cohort, cohort_seed=cohort_seed,
+                diag_global_grad=diag_global_grad,
+                checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
+                eval_every=eval_every, async_checkpoint=async_checkpoint,
+                faults=faults, max_rollbacks=max_rollbacks,
             )
-        )
+            return res
 
-    if cfg.deferred or faults is not None:
-        from repro.core import rounds as rounds_mod  # deferred: avoids cycle
+        if chunk is None or chunk > 0:
+            from repro.core import rounds as rounds_mod  # deferred: avoids cycle
 
-    xs = [x0]
-    fvals = [global_value_fn(cobjs, x0)]
-    queries, coss, disps, rrs, reps = [], [], [], [], []
-    drops, quars = [], []
-    sx = x0
-    for r in range(rounds):
+            if chunk is None:
+                chunk = rounds_mod.DEFAULT_CHUNK
+            _, res = rounds_mod.run_rounds(
+                cfg, rff, query_fn, cobjs, states, x0, global_value_fn,
+                rounds, chunk, diag_global_grad=diag_global_grad,
+                checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
+                eval_every=eval_every, async_checkpoint=async_checkpoint,
+                faults=faults, max_rollbacks=max_rollbacks,
+            )
+            return res
+
+        if checkpoint_dir:
+            raise ValueError("checkpoint_dir requires the scan driver (chunk != 0)")
+        if faults is not None:
+            # Loop oracle matches the scan engine: a never-active window runs
+            # the faults-free body (see rounds.run_rounds).
+            from repro.faults.injector import effective_config
+            faults = effective_config(faults, rounds)
+        mean_fn = lambda tree: jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), tree)
+
         if faults is None:
-            states, stats = round_jit(states, sx)
+            round_jit = jax.jit(
+                lambda states, sx: run_round(cfg, rff, query_fn, cobjs, states, sx, mean_fn, diag_global_grad)
+            )
         else:
-            states, stats = round_jit(states, sx, jnp.asarray(r, jnp.int32))
-        if cfg.deferred:
-            # Loop oracle for the scan engine's chunk boundary: repair after
-            # every round (the chunk=1 degenerate case of the deferred
-            # contract -- flags never persist across rounds here).
-            states, _ = rounds_mod.repair_flagged_clients(states, cfg)
-        sx = stats.server_x
-        if faults is not None and faults.tolerate:
-            # Loop oracle for the boundary quarantine reset (host-read flag,
-            # chunk=1 degenerate cadence -- see rounds.quarantine_reset_flagged)
-            states, _ = rounds_mod.quarantine_reset_flagged(states, cfg, sx)
-        xs.append(sx)
-        r1 = r + 1
-        if r1 % eval_every == 0 or r1 == rounds:
-            fvals.append(global_value_fn(cobjs, sx))
-        else:
-            fvals.append(jnp.full((), jnp.nan, jnp.float32))
-        queries.append(stats.queries_per_client)
-        coss.append(stats.mean_cos)
-        disps.append(stats.mean_disparity)
-        rrs.append(stats.refactor_rate)
-        reps.append(stats.repair_rate)
-        drops.append(stats.drop_rate)
-        quars.append(stats.quarantine_rate)
+            sum_fn = lambda a: jnp.sum(a, axis=0)
+            round_jit = jax.jit(
+                lambda states, sx, r: run_round(
+                    cfg, rff, query_fn, cobjs, states, sx, mean_fn, diag_global_grad,
+                    sum_fn=sum_fn, faults=faults, round_idx=r,
+                )
+            )
 
-    return SimResult(
-        xs=jnp.stack(xs),
-        f_values=jnp.stack([jnp.asarray(f, jnp.float32) for f in fvals]),
-        queries=jnp.stack(queries),
-        mean_cos=jnp.stack(coss),
-        mean_disparity=jnp.stack(disps),
-        refactor_rate=jnp.stack(rrs),
-        repair_rate=jnp.stack(reps),
-        drop_rate=jnp.stack(drops),
-        quarantine_rate=jnp.stack(quars),
-    )
+        if cfg.deferred or faults is not None:
+            from repro.core import rounds as rounds_mod  # deferred: avoids cycle
+
+        xs = [x0]
+        fvals = [global_value_fn(cobjs, x0)]
+        queries, coss, disps, rrs, reps = [], [], [], [], []
+        drops, quars = [], []
+        sx = x0
+        for r in range(rounds):
+            if faults is None:
+                states, stats = round_jit(states, sx)
+            else:
+                states, stats = round_jit(states, sx, jnp.asarray(r, jnp.int32))
+            if cfg.deferred:
+                # Loop oracle for the scan engine's chunk boundary: repair after
+                # every round (the chunk=1 degenerate case of the deferred
+                # contract -- flags never persist across rounds here).
+                states, _ = rounds_mod.repair_flagged_clients(states, cfg)
+            sx = stats.server_x
+            if faults is not None and faults.tolerate:
+                # Loop oracle for the boundary quarantine reset (host-read flag,
+                # chunk=1 degenerate cadence -- see rounds.quarantine_reset_flagged)
+                states, _ = rounds_mod.quarantine_reset_flagged(states, cfg, sx)
+            xs.append(sx)
+            r1 = r + 1
+            if r1 % eval_every == 0 or r1 == rounds:
+                fvals.append(global_value_fn(cobjs, sx))
+            else:
+                fvals.append(jnp.full((), jnp.nan, jnp.float32))
+            queries.append(stats.queries_per_client)
+            coss.append(stats.mean_cos)
+            disps.append(stats.mean_disparity)
+            rrs.append(stats.refactor_rate)
+            reps.append(stats.repair_rate)
+            drops.append(stats.drop_rate)
+            quars.append(stats.quarantine_rate)
+
+        return SimResult(
+            xs=jnp.stack(xs),
+            f_values=jnp.stack([jnp.asarray(f, jnp.float32) for f in fvals]),
+            queries=jnp.stack(queries),
+            mean_cos=jnp.stack(coss),
+            mean_disparity=jnp.stack(disps),
+            refactor_rate=jnp.stack(rrs),
+            repair_rate=jnp.stack(reps),
+            drop_rate=jnp.stack(drops),
+            quarantine_rate=jnp.stack(quars),
+        )
 
 
 def optimal_gamma_star(

@@ -53,6 +53,7 @@ from repro.core import algorithms as alg
 from repro.core import federated as fed
 from repro.core import gp_surrogate as gp
 from repro.core import rff as rfflib
+from repro.core import telemetry
 
 GlobalValueFn = Callable[[Any, jax.Array], jax.Array]
 
@@ -117,7 +118,8 @@ def _round_body(cfg, rff, query_fn, cobjs, mean_fn, eval_fn, diag_global_grad,
             )
 
         def do_eval():
-            return jnp.asarray(eval_fn(cobjs, stats.server_x), jnp.float32)
+            with telemetry.scope("fzoo.eval"):
+                return jnp.asarray(eval_fn(cobjs, stats.server_x), jnp.float32)
 
         if eval_every == 1:
             f = do_eval()
@@ -243,18 +245,26 @@ class ChunkStep:
     reach the executable as ARGUMENTS: left in the closure, jit would embed
     them in the program as literals, putting a model's weights into every
     compiled chunk step and compile-cache entry.
+
+    A trace of ``chunk_fn`` runs under the ``fzoo.chunk_step.trace`` span and
+    counts in ``telemetry``'s ``chunk_traces``; the first call of each new
+    jitted step (lowering, compiling or loading the executable from the
+    persistent cache, the first enqueue) under ``fzoo.chunk_step.compile``.
     """
 
     def __init__(self, chunk_fn):
         self._chunk_fn = chunk_fn
         self._traced: dict = {}  # argument structure/shapes -> (jitted, arrays)
+        self._called: set = set()  # keys whose jitted step has run
 
     def _get(self, states, cobjs, sx, offset):
         args = (states, cobjs, sx, offset)
         leaves, tree = jax.tree_util.tree_flatten(args)
         key = (tree, tuple((np.shape(a), str(jnp.result_type(a))) for a in leaves))
         if key not in self._traced:
-            closed, out_shape = jax.make_jaxpr(self._chunk_fn, return_shape=True)(*args)
+            with telemetry.span("fzoo.chunk_step.trace"):
+                telemetry.count("chunk_traces")
+                closed, out_shape = jax.make_jaxpr(self._chunk_fn, return_shape=True)(*args)
             body, out_tree = closed.jaxpr, jax.tree_util.tree_structure(out_shape)
 
             @partial(jax.jit, donate_argnums=(1, 2))
@@ -265,14 +275,19 @@ class ChunkStep:
                 return states, _hist_write(hist, ys, offset), sx
 
             self._traced[key] = (run, list(closed.consts))
-        return self._traced[key]
+        return key, *self._traced[key]
 
     def __call__(self, states, hist, cobjs, sx, offset):
-        run, consts = self._get(states, cobjs, sx, offset)
-        return run(consts, states, hist, cobjs, sx, offset)
+        key, run, consts = self._get(states, cobjs, sx, offset)
+        if key in self._called:
+            return run(consts, states, hist, cobjs, sx, offset)
+        with telemetry.span("fzoo.chunk_step.compile"):
+            out = run(consts, states, hist, cobjs, sx, offset)
+        self._called.add(key)
+        return out
 
     def lower(self, states, hist, cobjs, sx, offset):
-        run, consts = self._get(states, cobjs, sx, offset)
+        _, run, consts = self._get(states, cobjs, sx, offset)
         return run.lower(consts, states, hist, cobjs, sx, offset)
 
 
@@ -638,7 +653,8 @@ def run_rounds(
                 # single-file restores (host arrays) onto the mesh.
                 states = fed.shard_clients(mesh, states)
     if hist is None:
-        hist = history_init(rounds, x0, global_value_fn(cobjs, x0))
+        with telemetry.span("fzoo.eval_x0"):
+            hist = history_init(rounds, x0, global_value_fn(cobjs, x0))
 
     sx = hist.xs[start]
     fcfg = faults
@@ -689,96 +705,98 @@ def run_rounds(
     try:
         while done < rounds:
             k = min(chunk, rounds - done)
-            states, hist, sx = step_for(k)(
-                states, hist, cobjs, sx, jnp.asarray(done, jnp.int32)
-            )
+            with telemetry.span("fzoo.chunk", offset=done, k=k):
+                states, hist, sx = step_for(k)(
+                    states, hist, cobjs, sx, jnp.asarray(done, jnp.int32)
+                )
             done += k
             chunks_done += 1
-            # Deferred-repair pass BETWEEN scan dispatches, decided ON
-            # DEVICE: no flag read, no host sync -- the loop keeps running
-            # ahead of the device (DESIGN.md Sec. 3).
-            states = boundary_repair_on_device(states, cfg, mesh=mesh)
-            if fcfg is not None and fcfg.tolerate:
-                # Re-admit quarantined clients from the global iterate;
-                # decided on device like the repair gate above.
-                states = boundary_quarantine_reset(states, cfg, sx, mesh=mesh)
-            ok = True
-            if fcfg is not None:
-                # THE one documented host sync of the faulted boundary: a
-                # finiteness check of the (d,) server iterate, gating the
-                # checkpoint write so a poisoned state is never persisted.
-                ok = bool(np.isfinite(np.asarray(jax.device_get(sx))).all())
-            wrote_ok = True
-            if ok and checkpoint_dir and (
-                chunks_done % max(checkpoint_every, 1) == 0 or done == rounds
-            ):
-                # Snapshot to host BEFORE the next chunk donates these
-                # buffers; the file write itself overlaps the next chunk's
-                # compute on the writer thread.
-                payload = ckpt_io.prepare_round_state(states, hist, mesh=mesh)
-                try:
-                    if writer is not None:
-                        # A submit surfaces the PREVIOUS boundary's write
-                        # error; rolling back to the last good step handles
-                        # both boundaries identically.
-                        writer.submit(partial(
-                            ckpt_io.write_round_state, checkpoint_dir, done,
-                            payload, run_meta,
-                        ))
-                        if done >= rounds:
-                            # FINAL boundary: there is no next submit to
-                            # surface this write's error, and raising it from
-                            # the post-loop drain would escape the rollback
-                            # machinery entirely.  Drain NOW so a failed last
-                            # write rolls back like any other boundary.
-                            writer.wait()
-                    else:
-                        ckpt_io.write_round_state(checkpoint_dir, done, payload,
-                                                  extra_meta=run_meta)
-                except OSError as e:
-                    if fcfg is None:
-                        raise
-                    print(f"[repro.rounds] checkpoint write failed at round "
-                          f"{done}: {e}")
-                    wrote_ok = False
-            if fcfg is not None and (not ok or not wrote_ok):
-                reason = ("non-finite server iterate" if not ok
-                          else "checkpoint write failure")
-                if not checkpoint_dir:
-                    raise FloatingPointError(
-                        f"{reason} at round {done} with no checkpoint_dir to "
-                        "roll back to (chunk rollback needs checkpointing)"
-                    )
-                if rollbacks >= max_rollbacks:
-                    raise FloatingPointError(
-                        f"{reason} at round {done}: rollback budget "
-                        f"max_rollbacks={max_rollbacks} exhausted"
-                    )
-                rollbacks += 1
-                if writer is not None:
+            with telemetry.span("fzoo.boundary"):
+                # Deferred-repair pass BETWEEN scan dispatches, decided ON
+                # DEVICE: no flag read, no host sync -- the loop keeps running
+                # ahead of the device (DESIGN.md Sec. 3).
+                states = boundary_repair_on_device(states, cfg, mesh=mesh)
+                if fcfg is not None and fcfg.tolerate:
+                    # Re-admit quarantined clients from the global iterate;
+                    # decided on device like the repair gate above.
+                    states = boundary_quarantine_reset(states, cfg, sx, mesh=mesh)
+                ok = True
+                if fcfg is not None:
+                    # THE one documented host sync of the faulted boundary: a
+                    # finiteness check of the (d,) server iterate, gating the
+                    # checkpoint write so a poisoned state is never persisted.
+                    ok = bool(np.isfinite(np.asarray(jax.device_get(sx))).all())
+                wrote_ok = True
+                if ok and checkpoint_dir and (
+                    chunks_done % max(checkpoint_every, 1) == 0 or done == rounds
+                ):
+                    # Snapshot to host BEFORE the next chunk donates these
+                    # buffers; the file write itself overlaps the next chunk's
+                    # compute on the writer thread.
+                    payload = ckpt_io.prepare_round_state(states, hist, mesh=mesh)
                     try:
-                        writer.wait()
-                    except OSError:
-                        pass  # the failed write IS the fault being rolled back
-                print(f"[repro.rounds] ROLLBACK {rollbacks}/{max_rollbacks} at "
-                      f"round {done} ({reason}): restoring last good checkpoint")
-                r_states, r_hist, r_start = _restore_newest_good(
-                    checkpoint_dir, run_meta, rounds, x0, states, mesh
-                )
-                if r_hist is None:
-                    raise FloatingPointError(
-                        f"rollback at round {done} failed: no restorable "
-                        f"checkpoint under {checkpoint_dir!r}"
+                        if writer is not None:
+                            # A submit surfaces the PREVIOUS boundary's write
+                            # error; rolling back to the last good step handles
+                            # both boundaries identically.
+                            writer.submit(partial(
+                                ckpt_io.write_round_state, checkpoint_dir, done,
+                                payload, run_meta,
+                            ))
+                            if done >= rounds:
+                                # FINAL boundary: there is no next submit to
+                                # surface this write's error, and raising it from
+                                # the post-loop drain would escape the rollback
+                                # machinery entirely.  Drain NOW so a failed last
+                                # write rolls back like any other boundary.
+                                writer.wait()
+                        else:
+                            ckpt_io.write_round_state(checkpoint_dir, done, payload,
+                                                      extra_meta=run_meta)
+                    except OSError as e:
+                        if fcfg is None:
+                            raise
+                        print(f"[repro.rounds] checkpoint write failed at round "
+                              f"{done}: {e}")
+                        wrote_ok = False
+                if fcfg is not None and (not ok or not wrote_ok):
+                    reason = ("non-finite server iterate" if not ok
+                              else "checkpoint write failure")
+                    if not checkpoint_dir:
+                        raise FloatingPointError(
+                            f"{reason} at round {done} with no checkpoint_dir to "
+                            "roll back to (chunk rollback needs checkpointing)"
+                        )
+                    if rollbacks >= max_rollbacks:
+                        raise FloatingPointError(
+                            f"{reason} at round {done}: rollback budget "
+                            f"max_rollbacks={max_rollbacks} exhausted"
+                        )
+                    rollbacks += 1
+                    if writer is not None:
+                        try:
+                            writer.wait()
+                        except OSError:
+                            pass  # the failed write IS the fault being rolled back
+                    print(f"[repro.rounds] ROLLBACK {rollbacks}/{max_rollbacks} at "
+                          f"round {done} ({reason}): restoring last good checkpoint")
+                    r_states, r_hist, r_start = _restore_newest_good(
+                        checkpoint_dir, run_meta, rounds, x0, states, mesh
                     )
-                states, hist, done = r_states, r_hist, r_start
-                if mesh is not None:
-                    states = fed.shard_clients(mesh, states)
-                sx = hist.xs[done]
-                if not fcfg.tolerate:
-                    print("[repro.rounds] re-running with fault tolerance "
-                          "FORCED ON")
-                    fcfg = dataclasses.replace(fcfg, tolerate=True)
-                chunks_done = 0
+                    if r_hist is None:
+                        raise FloatingPointError(
+                            f"rollback at round {done} failed: no restorable "
+                            f"checkpoint under {checkpoint_dir!r}"
+                        )
+                    states, hist, done = r_states, r_hist, r_start
+                    if mesh is not None:
+                        states = fed.shard_clients(mesh, states)
+                    sx = hist.xs[done]
+                    if not fcfg.tolerate:
+                        print("[repro.rounds] re-running with fault tolerance "
+                              "FORCED ON")
+                        fcfg = dataclasses.replace(fcfg, tolerate=True)
+                    chunks_done = 0
     finally:
         if writer is not None:
             writer.wait()
