@@ -26,9 +26,10 @@ The summary sums operations by instruction name over every executable, so an
 instruction of another executable that shares a name with one of the chunk
 step's counts under that one's scope.
 
-    python3 bench/phases.py --workload <cell> --seed <n> [--seconds 30] [--out F]
+    python3 bench/phases.py --workload <cell> --seed <n>
 
-runs the cell's traced window as ``bench/run.py --trace 1`` does (no check),
+runs the cell's traced window as ``bench/run.py --trace 1 --seconds
+<run_seconds>`` does (no check),
 reduces the trace with both modules and prints one JSON object: the
 per-layer metrics, the readings above, the job times and job 0's F(x_r)
 values.  It needs a TPU.
@@ -139,8 +140,10 @@ def reduce_phases(planes, op_names: Optional[dict] = None,
                   n_devices: Optional[int] = None) -> dict:
     """Every reading of this module from one trace, as a JSON-able dict:
     ``span_s`` and ``span_n`` per job, ``idle_by_phase`` summed over the
-    jobs, ``scopes`` (device seconds, per device), ``chunk_leads_s`` (from
-    the first device; empty when the counts of spans and executions differ)."""
+    jobs, ``scopes`` (device seconds, per device), ``unscoped_s`` and
+    ``unscoped_top`` (the chunk step's device seconds under no program scope,
+    and its ten largest such instructions), ``chunk_leads_s`` (from the first
+    device; empty when the counts of spans and executions differ)."""
     op_names = op_names or {}
     host = _host(planes)
     jobs = [(e.start, e.end) for e in host if e.name == tr.JOB_SPAN]
@@ -165,6 +168,7 @@ def reduce_phases(planes, op_names: Optional[dict] = None,
 
     idle: dict = defaultdict(float)
     scopes: dict = defaultdict(float)
+    unscoped: dict = defaultdict(float)
     leads = []
     for k, dev in enumerate(devs):
         busy, chunk_op_s, mods = _device_pass(dev, jobs[0][0], jobs[-1][1])
@@ -174,13 +178,19 @@ def reduce_phases(planes, op_names: Optional[dict] = None,
         for sc in SCOPES:
             scopes[sc] += sum(v for n, v in chunk_op_s.items()
                               if sc in op_names.get(n, "")) / len(devs)
+        for n, v in chunk_op_s.items():
+            if PREFIX not in op_names.get(n, ""):
+                unscoped[n] += v / len(devs)
         if k == 0:
             chunks = [e.start for spans in by_job for e in spans if e.name == "fzoo.chunk"]
             runs = [s for s, _ in mods if _job_of(s, jobs) is not None]
             leads = [h - d for h, d in zip(chunks, runs)] if len(chunks) == len(runs) else []
+    top = sorted(unscoped.items(), key=lambda x: -x[1])[:10]
     return {"jobs": len(jobs), "span_s": dict(span_s), "span_n": dict(span_n),
             "idle_by_phase": dict(sorted(idle.items(), key=lambda x: -x[1])),
-            "scopes": dict(scopes), "chunk_leads_s": leads}
+            "scopes": dict(scopes), "unscoped_s": sum(unscoped.values()),
+            "unscoped_top": [[f"{n} {op_names.get(n, '')[-120:]}".strip(), v] for n, v in top],
+            "chunk_leads_s": leads}
 
 
 def scope_share(t, scope: str) -> Optional[float]:
@@ -204,8 +214,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, default=30.0)
-    ap.add_argument("--out", help="also write the JSON object to this file")
     args = ap.parse_args(argv)
     sys.path.insert(1, os.path.join(os.path.dirname(BENCH_DIR), "src"))
 
@@ -223,6 +231,7 @@ def main(argv=None) -> int:
     import peaks as peaks_mod
 
     log = lambda msg: print(msg, file=sys.stderr, flush=True)
+    seconds = float(cell.spec["run_seconds"])
     harness.enable_compile_cache(cell.root)
     runner = harness.Runner(cell, args.seed, devices)
     warm = runner.run_job(harness.WARMUP_JOB, keep_state=True)
@@ -232,7 +241,7 @@ def main(argv=None) -> int:
     setup_s = time.perf_counter() - t_start
     tdir = harness.trace_dir(cell.root, cell.name, args.seed)
     try:
-        jobs, window_s, reduced = harness.traced_window(runner, args.seconds, tdir, log)
+        jobs, window_s, reduced = harness.traced_window(runner, seconds, tdir, log)
         t = time.perf_counter()
         ph = reduce_phases(tr.load_planes(tr.find_trace(tdir)), op_names=reduced.op_names,
                            n_devices=len(devices))
@@ -243,7 +252,7 @@ def main(argv=None) -> int:
                                                  f"{cell.config['objective']}.py"))
     kind = devices[0].device_kind
     ctx = harness.RunContext(
-        cell=cell, seed=args.seed, seconds=args.seconds, trace=True, setup_s=setup_s,
+        cell=cell, seed=args.seed, seconds=seconds, trace=True, setup_s=setup_s,
         jobs=jobs, window_s=window_s, problem=runner.problem, cfg=runner.cfg,
         n_chips=len(devices), device_kind=kind,
         settings=harness.algo_settings(cell, runner.problem.dim),
@@ -257,11 +266,8 @@ def main(argv=None) -> int:
            "breakdown": reduced.breakdown(), "phases": ph,
            "job_s": [r.end - r.start for r in jobs], "warm_job_s": warm.end - warm.start,
            "compiles": [r.compiles for r in jobs],
+           "cache_misses": [warm.cache_misses] + [r.cache_misses for r in jobs],
            "f_values_job0": [float(v) for v in np.asarray(jobs[0].result.f_values)]}
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
     print(json.dumps(out))
     return 0
 

@@ -94,6 +94,13 @@ def test_scope_seconds_nest_and_keep_to_the_chunk_step():
         "fzoo.eval": 0.5})  # F(x_0)'s fusion.3 ran outside the chunk step
 
 
+def test_unscoped_chunk_step_time():
+    names = dict(OP_NAMES, **{"fusion.2": "jit(run)/while/body/mul"})
+    p = phases.reduce_phases(_planes(), names)
+    assert p["unscoped_s"] == pytest.approx(1.0)
+    assert p["unscoped_top"] == [["fusion.2 jit(run)/while/body/mul", pytest.approx(1.0)]]
+
+
 def test_chunk_leads_pair_spans_with_executions():
     assert phases.reduce_phases(_planes(), OP_NAMES)["chunk_leads_s"] == \
         pytest.approx([3.0 - 5.5, 6.5 - 8.0])

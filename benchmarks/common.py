@@ -1,7 +1,7 @@
 """Shared helpers for the paper-figure benchmarks.
 
-Each benchmark module exposes ``run(quick: bool) -> list[Row]``; run.py
-aggregates them into the ``name,us_per_call,derived`` CSV contract.
+Each benchmark module exposes ``run(quick: bool) -> list[Row]``;
+``python -m benchmarks`` prints them as ``name,us_per_call,derived`` CSV.
 Scale: CPU-sized reductions of the paper's settings (dims and rounds noted
 per row so EXPERIMENTS.md can compare trends, not absolute numbers).
 """

@@ -401,8 +401,8 @@ def chol_rank1_update(chol: jax.Array, x: jax.Array, sign: float, floor: jax.Arr
     Returns (L', ok) where ok is False if any pivot fell below ``floor``;
     on failure L' is unusable by contract (callers refactor).  O(cap^2) but a
     length-cap SEQUENTIAL column recurrence -- measured slower than one
-    blocked potrf at cap=128 on CPU (see benchmarks/kernels_bench.py), which
-    is why the hot path refreshes with potrf instead.  Kept as the textbook
+    blocked potrf at cap=128 on CPU, which is why the hot path refreshes
+    with potrf instead.  Kept as the textbook
     O(cap^2) row-replace primitive and validated against refactorization.
     """
     n = chol.shape[0]

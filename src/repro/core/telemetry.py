@@ -3,7 +3,7 @@
 * ``span(name, **meta)`` is ``jax.profiler.TraceAnnotation``: a host event
   in the profiler's trace, on the same clock as the device events beside
   it, with ``meta`` as its stats.  With no profiler session active it costs
-  about a microsecond, so spans stay in the code unconditionally.
+  under a microsecond, so spans stay in the code unconditionally.
 * ``scope(name)`` is ``jax.named_scope``: the operations traced inside it
   carry the name in their HLO ``op_name`` metadata.  It changes neither the
   program's numerics nor its device time.
@@ -55,10 +55,10 @@ _get_depth = 0
 _real_device_get = jax.device_get
 
 
-def count(name: str, n: int = 1) -> None:
-    """Add ``n`` to counter ``name``."""
+def count(name: str) -> None:
+    """Add one to counter ``name``."""
     with _lock:
-        _counts[name] += n
+        _counts[name] += 1
 
 
 def counters() -> dict:

@@ -3,8 +3,7 @@
 ``select_blocks(kind, ...)`` picks ``(block_n, block_cap)`` for the
 cap-tiled scoring / grad-mean kernels from a VMEM-footprint +
 arithmetic-intensity model keyed on the per-device roofline peaks in
-``repro.launch.mesh.DEVICE_ROOFLINE`` (the same table
-``benchmarks/roofline.py`` reports against).  The choice is a pure function
+``repro.launch.mesh.DEVICE_ROOFLINE``.  The choice is a pure function
 of ``(device_kind, kind, n_clients, n, cap, d)`` -- deterministic and therefore
 reproducible -- and is memoized in a process-level cache under exactly that
 key.  Callers that need a specific tiling (tests, `AlgoConfig` overrides)

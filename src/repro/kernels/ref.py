@@ -119,8 +119,7 @@ def uncertainty_scores_clients_fused(
 
     which XLA fuses into one elementwise pass + one reduction over the
     (N, n, cap) intermediates instead of the textbook form's three -- the
-    measured batched-over-vmapped scoring win on CPU (BENCH_kernels.json,
-    ``client_batched``).  The per-element cancellation before the reduction
+    measured batched-over-vmapped scoring win on CPU.  The per-element cancellation before the reduction
     is also the numerically kinder order.  The textbook
     ``uncertainty_scores_clients`` above stays as the ground-truth oracle
     the tests compare against; the Pallas tile kernels use this same

@@ -18,7 +18,6 @@ Per combo it writes artifacts/dryrun/<arch>__<shape>__<mesh>.json with:
   per-category collective output bytes parsed from the optimized HLO,
   memory_analysis (argument/output/temp/generated code bytes per device),
   and wall-clock lower/compile times.
-benchmarks/roofline.py turns these into the three roofline terms.
 """
 
 import argparse

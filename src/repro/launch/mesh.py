@@ -52,8 +52,8 @@ ICI_BW = 50e9  # bytes/s per link
 HBM_PER_CHIP = 16e9  # bytes
 
 #: Roofline peaks keyed by ``jax.Device.device_kind`` -- the SINGLE source
-#: for ``benchmarks/roofline.py`` (communication/FLOP envelopes) and
-#: ``repro.kernels.autotune`` (block-size selection).  ``vmem_bytes`` is the
+#: for ``repro.kernels.autotune`` (block-size selection) and the kernel
+#: audit's VMEM budget.  ``vmem_bytes`` is the
 #: fast on-chip working-set budget the kernel tiles must fit in.  The
 #: ``cpu`` entry is the interpret-mode stand-in (an L2-sized budget keeps
 #: interpret-mode block choices moderate); its numbers are not a device's.

@@ -145,8 +145,9 @@ def test_device_get_counting_nests_and_restores():
 
 def test_compile_events_are_counted():
     f = jax.jit(lambda x: x * 3 - 1)
+    x = jnp.arange(7.0).block_until_ready()
     before = telemetry.counters()["compiles"]
-    f(jnp.zeros((7,))).block_until_ready()
+    f(x).block_until_ready()
     assert telemetry.counters()["compiles"] - before == 1
-    f(jnp.ones((7,))).block_until_ready()  # the same executable
+    f(x).block_until_ready()  # the same executable
     assert telemetry.counters()["compiles"] - before == 1
