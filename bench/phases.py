@@ -22,17 +22,18 @@ planes of a trace (``trace_reduce.load_planes``), ``reduce_phases`` reads:
 ``scope_share`` is the per-layer metrics' reading of a scope from a
 ``trace_reduce.TraceSummary``: the device seconds of the traced window's
 operations whose name the chunk step's HLO gives that scope, over busy time.
-The summary sums operations by instruction name over every executable, so an
-instruction of another executable that shares a name with one of the chunk
-step's counts under that one's scope.
+It is an upper bound: the summary sums operations by instruction name over
+every executable, so an instruction of another executable that shares a
+name with one of the chunk step's counts under that one's scope.
+
+This module is the part of a trace's reduction that ``trace_reduce.py``
+does not hold yet; it goes when ``trace_reduce`` takes in these readings.
 
     python3 bench/phases.py --workload <cell> --seed <n>
 
-runs the cell's traced window as ``bench/run.py --trace 1 --seconds
-<run_seconds>`` does (no check),
-reduces the trace with both modules and prints one JSON object: the
-per-layer metrics, the readings above, the job times and job 0's F(x_r)
-values.  It needs a TPU.
+runs ``bench/run.py --workload <cell> --seed <n> --seconds <run_seconds>
+--trace 1`` and prints its result line with the readings above added as
+``phases``.  It needs a TPU.
 """
 
 from __future__ import annotations
@@ -40,7 +41,6 @@ from __future__ import annotations
 import bisect
 import json
 import os
-import shutil
 import sys
 import time
 from array import array
@@ -58,11 +58,6 @@ OUTSIDE = "outside fzoo.simulate"
 #: The program's device scopes (``fzoo.local`` includes its three sub-scopes).
 SCOPES = ("fzoo.local", "fzoo.local.update", "fzoo.local.score", "fzoo.local.grad",
           "fzoo.aggregate", "fzoo.round_end", "fzoo.eval")
-
-
-def _host(planes) -> list:
-    return sorted((e for p in planes if p.name.startswith("/host:")
-                   for evs in p.lines.values() for e in evs), key=lambda e: e.start)
 
 
 def _job_of(t: float, jobs: list) -> Optional[int]:
@@ -84,39 +79,21 @@ def _segments(spans: list, lo: float, hi: float) -> list:
     return out
 
 
-class _Busy:
-    """The union of a device's operation intervals, for busy time in any range."""
-
-    def __init__(self, starts, ends):
-        order = sorted(range(len(starts)), key=starts.__getitem__)
-        self.s, self.e, cum = [], [], [0.0]
-        for i in order:
-            s, e = starts[i], ends[i]
-            if self.e and s <= self.e[-1]:
-                if e > self.e[-1]:
-                    cum[-1] += e - self.e[-1]
-                    self.e[-1] = e
-                continue
-            self.s.append(s)
-            self.e.append(e)
-            cum.append(cum[-1] + e - s)
-        self.cum = cum  # cum[k]: union length of the first k merged intervals
-
-    def within(self, lo: float, hi: float) -> float:
-        i = bisect.bisect_right(self.e, lo)  # first interval ending after lo
-        j = bisect.bisect_left(self.s, hi)  # intervals starting before hi
-        if j <= i:
-            return 0.0
-        total = self.cum[j] - self.cum[i]
-        total -= max(0.0, lo - self.s[i])
-        total -= max(0.0, self.e[j - 1] - hi)
-        return max(total, 0.0)
+def _idle_within(gaps: list, gap_starts: list, lo: float, hi: float) -> float:
+    """Idle seconds in [lo, hi]: its overlap with the sorted, disjoint gaps."""
+    total = 0.0
+    for a, b in gaps[max(bisect.bisect_right(gap_starts, lo) - 1, 0):]:
+        if a >= hi:
+            break
+        total += max(0.0, min(b, hi) - max(a, lo))
+    return total
 
 
 def _device_pass(dev, lo: float, hi: float):
-    """One pass over a device's operations inside [lo, hi]: its ``_Busy``,
-    the chunk step's device seconds by instruction name, and the chunk
-    step's executions."""
+    """One pass over a device's operations inside [lo, hi]: every stretch
+    that no operation covers (``trace_reduce.busy_and_gaps``), the chunk
+    step's device seconds by instruction name, and the chunk step's
+    executions."""
     mods = sorted((e.start, e.end) for e in dev.lines.get("XLA Modules", ())
                   if e.name.startswith(tr.CHUNK_MODULE))
     mod_starts = [s for s, _ in mods]
@@ -133,7 +110,8 @@ def _device_pass(dev, lo: float, hi: float):
         k = bisect.bisect_right(mod_starts, ev.start) - 1
         if k >= 0 and ev.start <= mods[k][1]:
             chunk_op_s[tr.instr_name(ev.name)] += e - s
-    return _Busy(starts, ends), chunk_op_s, mods
+    _, gaps = tr.busy_and_gaps(starts, ends, lo, hi, keep=len(starts) + 1)
+    return sorted(gaps), chunk_op_s, mods
 
 
 def reduce_phases(planes, op_names: Optional[dict] = None,
@@ -145,7 +123,8 @@ def reduce_phases(planes, op_names: Optional[dict] = None,
     and its ten largest such instructions), ``chunk_leads_s`` (from the first
     device; empty when the counts of spans and executions differ)."""
     op_names = op_names or {}
-    host = _host(planes)
+    host = sorted((e for p in planes if p.name.startswith("/host:")
+                   for evs in p.lines.values() for e in evs), key=lambda e: e.start)
     jobs = [(e.start, e.end) for e in host if e.name == tr.JOB_SPAN]
     if not jobs:
         raise ValueError(f"the trace holds no {tr.JOB_SPAN!r} span")
@@ -171,10 +150,11 @@ def reduce_phases(planes, op_names: Optional[dict] = None,
     unscoped: dict = defaultdict(float)
     leads = []
     for k, dev in enumerate(devs):
-        busy, chunk_op_s, mods = _device_pass(dev, jobs[0][0], jobs[-1][1])
+        gaps, chunk_op_s, mods = _device_pass(dev, jobs[0][0], jobs[-1][1])
+        gap_starts = [a for a, _ in gaps]
         for (s, end), spans in zip(jobs, by_job):
             for a, b, name in _segments(spans, s, end):
-                idle[name] += ((b - a) - busy.within(a, b)) / len(devs)
+                idle[name] += _idle_within(gaps, gap_starts, a, b) / len(devs)
         for sc in SCOPES:
             scopes[sc] += sum(v for n, v in chunk_op_s.items()
                               if sc in op_names.get(n, "")) / len(devs)
@@ -194,20 +174,21 @@ def reduce_phases(planes, op_names: Optional[dict] = None,
 
 
 def scope_share(t, scope: str) -> Optional[float]:
-    """Share of busy time (%) in operations under ``scope`` (see the module
-    docstring), or None when no operation carries it."""
+    """Share of busy time (%) in operations under ``scope``, or None when no
+    operation carries it.  An upper bound: ``TraceSummary.op_s`` sums
+    operations by instruction name over every executable of the window, so
+    an instruction of another executable (F(x_0), the initial states) that
+    shares a name with one of the chunk step's counts under that one's
+    scope.  ``reduce_phases``'s ``scopes`` keep to the chunk step."""
     if t is None or t.busy_s <= 0:
         return None
     seconds = sum(v for n, v in t.op_s.items() if scope in t.op_names.get(n, ""))
     return 100.0 * seconds / t.busy_s if seconds > 0 else None
 
 
-# ---------------------------------------------------------------------------
-# a traced window that keeps its trace
-# ---------------------------------------------------------------------------
-
-
 def main(argv=None) -> int:
+    """``bench/run.py --trace 1`` (``harness.run``), with this module's
+    readings of the trace added to the result line as ``phases``."""
     import argparse
 
     t_start = time.perf_counter()
@@ -226,49 +207,20 @@ def main(argv=None) -> int:
     except (harness.NoAccelerator, KeyError, FileNotFoundError) as e:
         print(f"phases: {e}", file=sys.stderr)
         return 2
-    import numpy as np
+    got = {}
+    traced_window = harness.traced_window
 
-    import peaks as peaks_mod
+    def keep_phases(runner, seconds, tdir, log):
+        jobs, window_s, reduced = traced_window(runner, seconds, tdir, log)
+        got["phases"] = reduce_phases(tr.load_planes(tr.find_trace(tdir)), reduced.op_names,
+                                      n_devices=len(devices))
+        return jobs, window_s, reduced
 
+    harness.traced_window = keep_phases
     log = lambda msg: print(msg, file=sys.stderr, flush=True)
-    seconds = float(cell.spec["run_seconds"])
-    harness.enable_compile_cache(cell.root)
-    runner = harness.Runner(cell, args.seed, devices)
-    warm = runner.run_job(harness.WARMUP_JOB, keep_state=True)
-    if warm.error is not None:
-        raise RuntimeError(f"warm-up job failed: {warm.error}")
-    warm.state = None
-    setup_s = time.perf_counter() - t_start
-    tdir = harness.trace_dir(cell.root, cell.name, args.seed)
-    try:
-        jobs, window_s, reduced = harness.traced_window(runner, seconds, tdir, log)
-        t = time.perf_counter()
-        ph = reduce_phases(tr.load_planes(tr.find_trace(tdir)), op_names=reduced.op_names,
-                           n_devices=len(devices))
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
-    log(f"phases reduced in {time.perf_counter() - t:.1f} s")
-    obj = harness.load_module(harness.bench_file(cell.root, "objectives",
-                                                 f"{cell.config['objective']}.py"))
-    kind = devices[0].device_kind
-    ctx = harness.RunContext(
-        cell=cell, seed=args.seed, seconds=seconds, trace=True, setup_s=setup_s,
-        jobs=jobs, window_s=window_s, problem=runner.problem, cfg=runner.cfg,
-        n_chips=len(devices), device_kind=kind,
-        settings=harness.algo_settings(cell, runner.problem.dim),
-        query_flops=obj.query_flops(cell.config), peaks=peaks_mod.for_device(kind),
-        reduced=reduced)
-    out = {"workload": cell.name, "seed": args.seed, "setup_s": setup_s,
-           "metrics": harness.compute_metrics(ctx, cell.per_layer),
-           "busy_s": reduced.busy_s, "window_s": reduced.window_s,
-           "idle_s": reduced.window_s - reduced.busy_s,
-           "scope_s_objective": reduced.scope_s.get("bench.objective"),
-           "breakdown": reduced.breakdown(), "phases": ph,
-           "job_s": [r.end - r.start for r in jobs], "warm_job_s": warm.end - warm.start,
-           "compiles": [r.compiles for r in jobs],
-           "cache_misses": [warm.cache_misses] + [r.cache_misses for r in jobs],
-           "f_values_job0": [float(v) for v in np.asarray(jobs[0].result.f_values)]}
-    print(json.dumps(out))
+    out = harness.run(cell, args.seed, float(cell.spec["run_seconds"]), True, t_start,
+                      devices, log=log)
+    print(json.dumps({**out, "phases": got["phases"]}))
     return 0
 
 

@@ -132,16 +132,40 @@ def _reader(name: str):
     return harness.load_module(os.path.join(BENCH, "metrics", f"{name}.py"))
 
 
+def _planes_apart():
+    """``_planes`` with F(x_0)'s instruction named apart from the chunk
+    step's."""
+    planes = _planes()
+    planes[1].lines["XLA Ops"][0] = ev("%fusion.9 = f32[] fusion(%x)", 2.5, 3.0)
+    return planes
+
+
 @pytest.mark.parametrize("name, seconds", [
     ("local_phase_share", 2.0 + 0.5),
     ("round_end_share", 1.0),
-    ("eval_share", 0.5 + 0.5),  # F(x_0)'s fusion.3 shares the chunk step's name
+    ("eval_share", 0.5),
 ])
 def test_scope_share_readers(name, seconds):
-    t = tr.reduce(_planes(), op_names=OP_NAMES)
+    t = tr.reduce(_planes_apart(), op_names=OP_NAMES)
     ctx = types.SimpleNamespace(reduced=t)
     assert t.busy_s == pytest.approx(4.5)
     assert _reader(name).read(ctx) == pytest.approx(100.0 * seconds / 4.5)
+
+
+@pytest.mark.parametrize("name, scope", [
+    ("local_phase_share", "fzoo.local"),
+    ("round_end_share", "fzoo.round_end"),
+    ("eval_share", "fzoo.eval"),
+])
+def test_scope_share_readers_bound_the_chunk_step_share(name, scope):
+    """Where another executable runs an instruction of the chunk step's name
+    (F(x_0)'s fusion.3 in ``_planes``), the reader counts it too: it reads at
+    least the chunk step's own share, never less."""
+    planes = _planes()
+    t = tr.reduce(planes, op_names=OP_NAMES)
+    own = phases.reduce_phases(planes, OP_NAMES)["scopes"][scope]
+    assert _reader(name).read(types.SimpleNamespace(reduced=t)) >= \
+        100.0 * own / t.busy_s - 1e-9
 
 
 @pytest.mark.parametrize("name", ["local_phase_share", "round_end_share", "eval_share"])
