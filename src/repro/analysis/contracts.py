@@ -214,14 +214,14 @@ def _chunk_fn(algo: str, defer_repair: bool, distributed: bool, length: int = 2,
     cfg, rff, quad, states, x0 = _fixture(algo, defer_repair)
     faults = _fault_fixture() if faulted else None
     if distributed:
-        cf = rounds_mod.dist_chunk_fn(cfg, _mesh(), rff, obj.quadratic_query,
+        cf = rounds_mod.dist_chunk_fn(cfg, _mesh(), obj.quadratic_query,
                                       obj.quadratic_global_value, length, 1, 4,
                                       faults=faults)
     else:
-        cf = rounds_mod.sim_chunk_fn(cfg, rff, obj.quadratic_query,
+        cf = rounds_mod.sim_chunk_fn(cfg, obj.quadratic_query,
                                      obj.quadratic_global_value, None, length,
                                      1, 4, faults=faults)
-    args = (states, quad, x0, jnp.int32(0))
+    args = (states, quad, x0, jnp.int32(0), rff)
     return cf, args
 
 
@@ -343,14 +343,14 @@ def _pool_chunk_fn(algo: str, distributed: bool, length: int = 2):
     cstates = pool.gather(idx, mesh=mesh)
     c_quad = jax.tree_util.tree_map(lambda a: jnp.asarray(a)[jnp.asarray(idx)], quad)
     if distributed:
-        cf = rounds_mod.dist_chunk_fn(ccfg, mesh, rff, obj.quadratic_query,
+        cf = rounds_mod.dist_chunk_fn(ccfg, mesh, obj.quadratic_query,
                                       obj.quadratic_global_value, length, 1, 4,
                                       faults=bcfg)
     else:
-        cf = rounds_mod.sim_chunk_fn(ccfg, rff, obj.quadratic_query,
+        cf = rounds_mod.sim_chunk_fn(ccfg, obj.quadratic_query,
                                      obj.quadratic_global_value, None, length,
                                      1, 4, faults=bcfg)
-    return cf, (cstates, c_quad, x0, jnp.int32(0))
+    return cf, (cstates, c_quad, x0, jnp.int32(0), rff)
 
 
 def _register_pool_engine(key: str, algo: str, n_array_psums: int) -> None:
@@ -386,11 +386,11 @@ _register_pool_engine("fedzo-pool", "fedzo", n_array_psums=1)
 def _chunk_step_donation(distributed: bool, faulted: bool = False) -> list[Violation]:
     from repro.core import rounds as rounds_mod
 
-    cf, (states, quad, x0, off) = _chunk_fn("fzoos", True, distributed,
-                                            faulted=faulted)
+    cf, (states, quad, x0, off, rff) = _chunk_fn("fzoos", True, distributed,
+                                                 faulted=faulted)
     hist = rounds_mod.history_init(4, x0, jnp.zeros((), jnp.float32))
     step = rounds_mod.make_chunk_step(cf)
-    text = step.lower(states, hist, quad, x0, off).as_text()
+    text = step.lower(states, hist, quad, x0, off, rff).as_text()
     n_leaves = len(jax.tree_util.tree_leaves((states, hist)))
     where = "distributed" if distributed else "simulate"
     if faulted:

@@ -386,12 +386,12 @@ def run_pooled_rounds(
         if skey not in steps:
             if mesh is None:
                 cf = rounds_mod.sim_chunk_fn(
-                    ccfg, rff, query_fn, global_value_fn, diag_global_grad,
+                    ccfg, query_fn, global_value_fn, diag_global_grad,
                     k, eval_every, rounds, faults=body_cfg,
                 )
             else:
                 cf = rounds_mod.dist_chunk_fn(
-                    ccfg, mesh, rff, query_fn, global_value_fn,
+                    ccfg, mesh, query_fn, global_value_fn,
                     k, eval_every, rounds, faults=body_cfg,
                 )
             steps[skey] = rounds_mod.make_chunk_step(cf)
@@ -422,7 +422,7 @@ def run_pooled_rounds(
             cstates = pool.gather(idx, mesh=mesh)
             c_cobjs = _gather_cobjs(cobjs_host, idx, pool.size, mesh)
             cstates, hist, sx = step_for(k, bcfg)(
-                cstates, hist, c_cobjs, sx, jnp.asarray(done, jnp.int32)
+                cstates, hist, c_cobjs, sx, jnp.asarray(done, jnp.int32), rff
             )
             done += k
             chunks_done += 1

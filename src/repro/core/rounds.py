@@ -12,6 +12,9 @@ This module scans ``run_round`` over K-round *chunks*:
 
   * one ``lax.scan`` per chunk -> one compile (per chunk length), one
     dispatch per chunk, zero host syncs mid-chunk;
+  * the traced and jitted chunk step outlives the front-door call
+    (``make_chunk_step``): a later call of the same shape neither traces
+    nor lowers it again;
   * ``global_value_fn`` is evaluated INSIDE the scanned body, so the
     F(x_r) curve is produced on device instead of round-tripping x_r;
   * per-round history (server iterates, F values, query counters,
@@ -38,7 +41,10 @@ path is the equivalence oracle for the tests.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
+import weakref
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -147,7 +153,6 @@ def _round_body(cfg, rff, query_fn, cobjs, mean_fn, eval_fn, diag_global_grad,
 
 def sim_chunk_fn(
     cfg: alg.AlgoConfig,
-    rff: Optional[rfflib.RFFParams],
     query_fn: alg.QueryFn,
     global_value_fn: GlobalValueFn,
     diag_global_grad,
@@ -156,13 +161,15 @@ def sim_chunk_fn(
     rounds_total: Optional[int] = None,
     faults=None,
 ):
-    """K scanned rounds with clients vmapped (single-process simulation)."""
+    """K scanned rounds with clients vmapped (single-process simulation):
+    ``chunk(states, cobjs, sx, offset, rff)``.  Carries its ``chunk_key``
+    (see ``make_chunk_step``)."""
     mean_fn = lambda tree: jax.tree_util.tree_map(
         lambda a: jnp.mean(a, axis=0), tree
     )
     sum_fn = (lambda a: jnp.sum(a, axis=0)) if faults is not None else None
 
-    def chunk(states, cobjs, sx, offset):
+    def chunk(states, cobjs, sx, offset, rff):
         body = _round_body(
             cfg, rff, query_fn, cobjs, mean_fn, global_value_fn, diag_global_grad,
             eval_every, rounds_total, sum_fn=sum_fn, faults=faults,
@@ -172,13 +179,14 @@ def sim_chunk_fn(
         )
         return states, sx, ys
 
+    chunk.chunk_key = (None, cfg, query_fn, global_value_fn, diag_global_grad,
+                       length, eval_every, rounds_total, faults)
     return chunk
 
 
 def dist_chunk_fn(
     cfg: alg.AlgoConfig,
     mesh: Mesh,
-    rff: Optional[rfflib.RFFParams],
     query_fn: alg.QueryFn,
     global_value_fn: GlobalValueFn,
     length: int,
@@ -189,7 +197,8 @@ def dist_chunk_fn(
     """K scanned rounds INSIDE shard_map: the per-round psum aggregation
     (plus one scalar pmean for F) stays the only collective.  The faulted
     body packs its live/quarantine counts INTO the psummed payload, so
-    masking adds no collective either."""
+    masking adds no collective either.  The RFF bank is replicated
+    (``P()``); like ``sim_chunk_fn``, the result carries its ``chunk_key``."""
     axes, mean_fn = fed.client_mean_fn(cfg, mesh)
     sum_fn = fed.client_sum_fn(mesh) if faults is not None else None
     cspec, rspec = P(axes), P()
@@ -201,7 +210,7 @@ def dist_chunk_fn(
     def eval_fn(cobjs, x):
         return jax.lax.pmean(global_value_fn(cobjs, x), axes)
 
-    def local_chunk(states, cobjs, sx, offset):
+    def local_chunk(states, cobjs, sx, offset, rff):
         body = _round_body(cfg, rff, query_fn, cobjs, mean_fn, eval_fn, None,
                            eval_every, rounds_total, sum_fn=sum_fn,
                            faults=faults)
@@ -210,13 +219,16 @@ def dist_chunk_fn(
         )
         return states, sx, ys
 
-    return jax.shard_map(
+    chunk = jax.shard_map(
         local_chunk,
         mesh=mesh,
-        in_specs=(cspec, cspec, rspec, rspec),
+        in_specs=(cspec, cspec, rspec, rspec, rspec),
         out_specs=(cspec, rspec, rspec),
         check_vma=False,
     )
+    chunk.chunk_key = (mesh, cfg, query_fn, global_value_fn, None, length,
+                       eval_every, rounds_total, faults)
+    return chunk
 
 
 def _hist_write(hist: alg.SimResult, ys, offset: jax.Array) -> alg.SimResult:
@@ -236,64 +248,136 @@ def _hist_write(hist: alg.SimResult, ys, offset: jax.Array) -> alg.SimResult:
     )
 
 
+@dataclasses.dataclass
+class _Program:
+    """One traced chunk step: its jitted ``run``, the closure constants it
+    takes as arguments, and whether it has run yet."""
+
+    run: Callable
+    consts: list
+    called: bool = False
+
+
 class ChunkStep:
-    """One jitted chunk step: ``step(states, hist, cobjs, sx, offset)``.
+    """One jitted chunk step: ``step(states, hist, cobjs, sx, offset, rff)``.
 
     The client states and the history buffers are donated: the engine
-    mutates them in place across the whole run.  Arrays that ``chunk_fn``
-    closes over -- the RFF map, the weights of a model-backed objective --
-    reach the executable as ARGUMENTS: left in the closure, jit would embed
+    mutates them in place across the whole run.  Everything that differs
+    between two calls of one program -- the states, the objectives, the RFF
+    bank each front-door call draws -- is an argument of ``chunk_fn``.  The
+    arrays it closes over (the weights of a model-backed objective) reach
+    the executable as ARGUMENTS too: left in the closure, jit would embed
     them in the program as literals, putting a model's weights into every
     compiled chunk step and compile-cache entry.
 
-    A trace of ``chunk_fn`` runs under the ``fzoo.chunk_step.trace`` span and
-    counts in ``telemetry``'s ``chunk_traces``; the first call of each new
-    jitted step (lowering, compiling or loading the executable from the
-    persistent cache, the first enqueue) under ``fzoo.chunk_step.compile``.
+    A program is traced once per argument structure, shapes, dtypes and
+    default matmul precision.  Its trace runs under the
+    ``fzoo.chunk_step.trace`` span and counts in ``telemetry``'s
+    ``chunk_traces``; the first call of each new jitted program (lowering,
+    compiling or loading the executable from the persistent cache, the first
+    enqueue) under ``fzoo.chunk_step.compile``.  ``programs`` shares the
+    traced programs of an earlier step of the same chunk function key
+    (``make_chunk_step``).
     """
 
-    def __init__(self, chunk_fn):
+    def __init__(self, chunk_fn, programs: Optional[dict] = None):
         self._chunk_fn = chunk_fn
-        self._traced: dict = {}  # argument structure/shapes -> (jitted, arrays)
-        self._called: set = set()  # keys whose jitted step has run
+        self._programs = {} if programs is None else programs
 
-    def _get(self, states, cobjs, sx, offset):
-        args = (states, cobjs, sx, offset)
+    def _get(self, states, cobjs, sx, offset, rff) -> _Program:
+        args = (states, cobjs, sx, offset, rff)
         leaves, tree = jax.tree_util.tree_flatten(args)
-        key = (tree, tuple((np.shape(a), str(jnp.result_type(a))) for a in leaves))
-        if key not in self._traced:
+        key = (tree, tuple((np.shape(a), str(jnp.result_type(a))) for a in leaves),
+               jax.config.jax_default_matmul_precision)
+        if key not in self._programs:
             with telemetry.span("fzoo.chunk_step.trace"):
                 telemetry.count("chunk_traces")
                 closed, out_shape = jax.make_jaxpr(self._chunk_fn, return_shape=True)(*args)
             body, out_tree = closed.jaxpr, jax.tree_util.tree_structure(out_shape)
 
             @partial(jax.jit, donate_argnums=(1, 2))
-            def run(consts, states, hist, cobjs, sx, offset):
-                flat = jax.tree_util.tree_leaves((states, cobjs, sx, offset))
+            def run(consts, states, hist, cobjs, sx, offset, rff):
+                flat = jax.tree_util.tree_leaves((states, cobjs, sx, offset, rff))
                 outs = jex_core.jaxpr_as_fun(jex_core.ClosedJaxpr(body, consts))(*flat)
                 states, sx, ys = jax.tree_util.tree_unflatten(out_tree, outs)
                 return states, _hist_write(hist, ys, offset), sx
 
-            self._traced[key] = (run, list(closed.consts))
-        return key, *self._traced[key]
+            self._programs[key] = _Program(run, list(closed.consts))
+        return self._programs[key]
 
-    def __call__(self, states, hist, cobjs, sx, offset):
-        key, run, consts = self._get(states, cobjs, sx, offset)
-        if key in self._called:
-            return run(consts, states, hist, cobjs, sx, offset)
+    def __call__(self, states, hist, cobjs, sx, offset, rff):
+        prog = self._get(states, cobjs, sx, offset, rff)
+        if prog.called:
+            return prog.run(prog.consts, states, hist, cobjs, sx, offset, rff)
         with telemetry.span("fzoo.chunk_step.compile"):
-            out = run(consts, states, hist, cobjs, sx, offset)
-        self._called.add(key)
+            out = prog.run(prog.consts, states, hist, cobjs, sx, offset, rff)
+        prog.called = True
         return out
 
-    def lower(self, states, hist, cobjs, sx, offset):
-        _, run, consts = self._get(states, cobjs, sx, offset)
-        return run.lower(consts, states, hist, cobjs, sx, offset)
+    def lower(self, states, hist, cobjs, sx, offset, rff):
+        prog = self._get(states, cobjs, sx, offset, rff)
+        return prog.run.lower(prog.consts, states, hist, cobjs, sx, offset, rff)
+
+
+#: Most chunk function keys whose programs ``make_chunk_step`` keeps.
+CHUNK_STEP_CACHE_SIZE = 16
+
+#: The traced programs of each chunk function key, least recently used first.
+_CHUNK_PROGRAMS: "collections.OrderedDict[tuple, dict]" = collections.OrderedDict()
+_CHUNK_PROGRAMS_LOCK = threading.Lock()
+
+
+def _weak_key(key: tuple, on_death=None) -> tuple:
+    """``key`` with each function in it held by a weak reference, which
+    compares by the function's identity while it lives.  A callable that
+    cannot be weakly referenced is kept as it is."""
+    out = []
+    for x in key:
+        if callable(x):
+            try:
+                x = weakref.ref(x, on_death)
+            except TypeError:
+                pass
+        out.append(x)
+    return tuple(out)
 
 
 def make_chunk_step(chunk_fn) -> ChunkStep:
-    """Jit one chunk step (see ``ChunkStep``)."""
-    return ChunkStep(chunk_fn)
+    """Jit one chunk step (see ``ChunkStep``), reusing the programs traced
+    for an equal ``chunk_fn.chunk_key``.
+
+    ``sim_chunk_fn`` and ``dist_chunk_fn`` key their chunk functions on
+    ``(mesh, cfg, query_fn, global_value_fn, diag_global_grad, length,
+    eval_every, rounds_total, faults)``.  The functions in it compare by
+    identity, the contract ``jax.jit`` keeps for a function object: one
+    function object stands for one computation, so whatever differs between
+    two calls of one key is an argument of the chunk function, never a
+    closure constant (DESIGN.md Sec. 3).  A hit
+    returns a step that shares the earlier step's traced programs and
+    counts in ``telemetry``'s ``chunk_step_reuses``; a chunk function
+    without a key gets a step of its own.
+
+    The cache holds at most ``CHUNK_STEP_CACHE_SIZE`` keys and only weak
+    references to their functions: a key goes when one of its functions is
+    garbage, with the programs and the closure constants they hold.
+    """
+    key = getattr(chunk_fn, "chunk_key", None)
+    if key is None:
+        return ChunkStep(chunk_fn)
+    cache = _CHUNK_PROGRAMS  # bound here: the callback below may run at exit
+    wkey = _weak_key(key)
+    with _CHUNK_PROGRAMS_LOCK:
+        programs = cache.get(wkey)
+        if programs is not None:
+            telemetry.count("chunk_step_reuses")
+            cache.move_to_end(wkey)
+        else:
+            programs, held = {}, []
+            held.append(_weak_key(key, lambda _: cache.pop(held[0], None)))
+            cache[held[0]] = programs
+            while len(cache) > CHUNK_STEP_CACHE_SIZE:
+                cache.popitem(last=False)
+    return ChunkStep(chunk_fn, programs)
 
 
 # ---------------------------------------------------------------------------
@@ -666,11 +750,11 @@ def run_rounds(
         skey = (k, fcfg)
         if skey not in steps:
             if mesh is None:
-                cf = sim_chunk_fn(cfg, rff, query_fn, global_value_fn,
+                cf = sim_chunk_fn(cfg, query_fn, global_value_fn,
                                   diag_global_grad, k, eval_every, rounds,
                                   faults=fcfg)
             else:
-                cf = dist_chunk_fn(cfg, mesh, rff, query_fn, global_value_fn,
+                cf = dist_chunk_fn(cfg, mesh, query_fn, global_value_fn,
                                    k, eval_every, rounds, faults=fcfg)
             steps[skey] = make_chunk_step(cf)
         return steps[skey]
@@ -707,7 +791,7 @@ def run_rounds(
             k = min(chunk, rounds - done)
             with telemetry.span("fzoo.chunk", offset=done, k=k):
                 states, hist, sx = step_for(k)(
-                    states, hist, cobjs, sx, jnp.asarray(done, jnp.int32)
+                    states, hist, cobjs, sx, jnp.asarray(done, jnp.int32), rff
                 )
             done += k
             chunks_done += 1

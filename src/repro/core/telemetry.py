@@ -14,7 +14,9 @@
   - ``device_gets``: ``jax.device_get`` calls made while
     ``counting_device_gets`` is active;
   - ``chunk_traces``: traces of a chunk function (``rounds.ChunkStep``
-    misses its trace cache).
+    misses its trace cache);
+  - ``chunk_step_reuses``: ``rounds.make_chunk_step`` calls that get the
+    traced programs of an earlier call with an equal chunk function key.
 
 Every span and scope of the program is named ``fzoo.<phase>``:
 
@@ -50,7 +52,7 @@ scope = jax.named_scope
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _lock = threading.Lock()
-_counts = {"compiles": 0, "device_gets": 0, "chunk_traces": 0}
+_counts = {"compiles": 0, "device_gets": 0, "chunk_traces": 0, "chunk_step_reuses": 0}
 _get_depth = 0
 _real_device_get = jax.device_get
 

@@ -233,9 +233,9 @@ def test_hlo_of_scanned_round_body_contains_no_eigh(quad):
         rff = rfflib.make_rff(jax.random.PRNGKey(1), cfg.n_features, cfg.dim,
                               cfg.lengthscale)
         states = alg.init_states(cfg, jax.random.PRNGKey(2), x0)
-        cf = rounds_mod.sim_chunk_fn(cfg, rff, obj.quadratic_query,
+        cf = rounds_mod.sim_chunk_fn(cfg, obj.quadratic_query,
                                      obj.quadratic_global_value, None, 2, 1, 4)
-        return jax.jit(cf).lower(states, quad, x0, jnp.int32(0)).as_text()
+        return jax.jit(cf).lower(states, quad, x0, jnp.int32(0), rff).as_text()
 
     deferred = lower_body(_fzoos_cfg(defer_repair=True))
     inline = lower_body(_fzoos_cfg(defer_repair=False))

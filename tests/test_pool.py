@@ -225,7 +225,7 @@ def test_one_executable_serves_every_cohort(quad8):
     cobjs_host = jax.tree_util.tree_map(
         lambda a: np.asarray(jax.device_get(a)), quad8)
     step = rounds_mod.make_chunk_step(rounds_mod.sim_chunk_fn(
-        ccfg, rff, obj.quadratic_query, obj.quadratic_global_value, None,
+        ccfg, obj.quadratic_query, obj.quadratic_global_value, None,
         2, 1, 6, faults=FaultConfig(),
     ))
     hist = rounds_mod.history_init(6, x0, obj.quadratic_global_value(quad8, x0))
@@ -234,7 +234,7 @@ def test_one_executable_serves_every_cohort(quad8):
         idx = pool_mod.sample_cohort(0, off, 8, 4)
         cstates = pool.gather(idx)
         cco = jax.tree_util.tree_map(lambda a: jnp.asarray(a[idx]), cobjs_host)
-        cstates, hist, sx = step(cstates, hist, cco, sx, jnp.int32(off))
+        cstates, hist, sx = step(cstates, hist, cco, sx, jnp.int32(off), rff)
         pool.scatter(idx, cstates)
         return hist, sx
 

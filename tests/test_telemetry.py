@@ -5,6 +5,7 @@ with ``ProfileData`` must hold every host span of the program, nested where
 it is called, and the compiled chunk step's HLO must name each device phase.
 """
 
+import collections
 import glob
 import os
 import re
@@ -49,6 +50,16 @@ def _query(cp, x, key):
         return obj.quadratic_query(cp, x, key)
 
 
+def _own_query():
+    """``_query`` as a new function object: no earlier call in this process
+    can have warmed a chunk step keyed on it (``rounds.make_chunk_step``)."""
+
+    def query(cp, x, key):
+        return _query(cp, x, key)
+
+    return query
+
+
 def _fzoo_spans(tdir: str) -> list:
     """The trace's ``fzoo.*`` host events as (name, start_ns, end_ns)."""
     from jax.profiler import ProfileData
@@ -67,17 +78,23 @@ def _inside(inner, outer) -> bool:
     return outer[1] <= inner[1] and inner[2] <= outer[2]
 
 
+def _traced_simulate(cfg, quad, query, seed: int, tdir: str) -> list:
+    """The ``fzoo.*`` host spans of one ``simulate`` call under the profiler."""
+    with jax.profiler.trace(tdir):
+        res = alg.simulate(cfg, jax.random.PRNGKey(seed), quad, query,
+                           obj.quadratic_global_value, ROUNDS, chunk=CHUNK)
+        jax.block_until_ready(res)
+    return _fzoo_spans(tdir)
+
+
 @pytest.mark.parametrize("algo", ["fzoos", "fedzo"])
 def test_host_spans_nest_and_count_traces(algo, quad, tmp_path):
     cfg = _cfg(algo)
-    before = telemetry.counters()["chunk_traces"]
-    with jax.profiler.trace(str(tmp_path)):
-        res = alg.simulate(cfg, jax.random.PRNGKey(3), quad, _query,
-                           obj.quadratic_global_value, ROUNDS, chunk=CHUNK)
-        jax.block_until_ready(res)
-    traces = telemetry.counters()["chunk_traces"] - before
+    query = _own_query()
+    before = telemetry.counters()
+    spans = _traced_simulate(cfg, quad, query, 3, str(tmp_path / "first"))
+    traces = telemetry.counters()["chunk_traces"] - before["chunk_traces"]
 
-    spans = _fzoo_spans(str(tmp_path))
     by = {}
     for s in spans:
         by.setdefault(s[0], []).append(s)
@@ -101,6 +118,16 @@ def test_host_spans_nest_and_count_traces(algo, quad, tmp_path):
     assert by["fzoo.chunk_step.trace"][0][2] <= by["fzoo.chunk_step.compile"][0][1]
     assert traces == len(by["fzoo.chunk_step.trace"]) == 1
 
+    # a second call of one shape reuses the step: no trace, no compile
+    again = _traced_simulate(cfg, quad, query, 4, str(tmp_path / "again"))
+    assert collections.Counter(s[0] for s in again) == {
+        "fzoo.simulate": 1, "fzoo.init": 1, "fzoo.eval_x0": 1,
+        "fzoo.chunk": n_chunks, "fzoo.boundary": n_chunks,
+    }
+    after = telemetry.counters()
+    assert after["chunk_traces"] - before["chunk_traces"] == 1
+    assert after["chunk_step_reuses"] - before["chunk_step_reuses"] == 1
+
 
 @pytest.mark.parametrize("algo", ["fzoos", "fedzo"])
 def test_chunk_step_hlo_names_each_phase(algo, quad):
@@ -112,9 +139,9 @@ def test_chunk_step_hlo_names_each_phase(algo, quad):
                               cfg.lengthscale)
     states = alg.init_states(cfg, jax.random.PRNGKey(2), x0)
     step = rounds_mod.make_chunk_step(rounds_mod.sim_chunk_fn(
-        cfg, rff, _query, obj.quadratic_global_value, None, CHUNK))
+        cfg, _query, obj.quadratic_global_value, None, CHUNK))
     hist = rounds_mod.history_init(ROUNDS, x0, jnp.zeros((), jnp.float32))
-    hlo = step.lower(states, hist, quad, x0, jnp.int32(0)).compile().as_text()
+    hlo = step.lower(states, hist, quad, x0, jnp.int32(0), rff).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', hlo)
     for sc in SCOPES[algo]:
         assert any(sc in n for n in names), sc

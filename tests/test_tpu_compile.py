@@ -152,11 +152,13 @@ def test_fzoos_chunk_step_takes_the_kernel_route_on_v5e(one_chip, monkeypatch):
     rff = rfflib.make_rff(jax.random.PRNGKey(1), cfg.n_features, d, cfg.lengthscale)
     states = alg.init_states(cfg, jax.random.PRNGKey(2), x0)
     hist = rounds_mod.history_init(rounds, x0, jnp.zeros((), jnp.float32))
-    step = rounds_mod.make_chunk_step(rounds_mod.sim_chunk_fn(
-        cfg, rff, obj.quadratic_query, obj.quadratic_global_value, None, rounds))
+    # A step of its own, not one that ``make_chunk_step`` may share: its trace
+    # has to see the route forced above.
+    step = rounds_mod.ChunkStep(rounds_mod.sim_chunk_fn(
+        cfg, obj.quadratic_query, obj.quadratic_global_value, None, rounds))
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (states, hist, cobjs, x0, jnp.int32(0)))
+        (states, hist, cobjs, x0, jnp.int32(0), rff))
     text = step.lower(*args).compile().as_text()
     # local-step scoring, local-step grad mean, round-end scoring
     assert text.count('custom_call_target="tpu_custom_call"') >= 3
